@@ -4,6 +4,10 @@ scheduler (independent batches land on separate lanes — the paper's
 multi-task overlap applied to inference).
 
     PYTHONPATH=src python examples/serve_lm.py --requests 4 --new-tokens 16
+
+Without ``--full`` it serves the small float32 test version of the config;
+``--full`` serves the registered config at its published widths with
+bfloat16 weights (e.g. ``--arch hymba_1_5b --full`` on one TPU v5e).
 """
 import argparse
 import time
@@ -26,10 +30,13 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=2)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--new-tokens", type=int, default=16)
+    ap.add_argument("--full", action="store_true",
+                    help="published widths with bfloat16 weights")
     args = ap.parse_args()
 
-    cfg = get_config(args.arch, reduced=True)
-    params = init_lm(jax.random.PRNGKey(0), cfg)
+    cfg = get_config(args.arch, reduced=not args.full)
+    params = init_lm(jax.random.PRNGKey(0), cfg,
+                     dtype=jnp.bfloat16 if args.full else jnp.float32)
     prefill = jax.jit(make_prefill_step(cfg))
     decode = jax.jit(make_decode_step(cfg))
 

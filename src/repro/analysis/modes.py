@@ -46,7 +46,7 @@ from ..core.element import AccessMode
 
 try:  # pragma: no cover - exercised indirectly everywhere
     import jax
-    from jax import core as _jcore
+    from jax.extend import core as _jcore
 except Exception:  # pragma: no cover - jax is a hard dep of the runtime
     jax = None
     _jcore = None

@@ -116,8 +116,8 @@ class Executor:
 def _run_device_element(e: ComputationalElement, jdev=None):
     """Execute a kernel/transfer element against its ManagedArray args.
 
-    ``jdev`` is the JAX device the element's lane is pinned to (None when a
-    single device is visible — the pre-multi-device behaviour)."""
+    ``jdev`` is the JAX device the element's lane is pinned to (None on a
+    single-device executor, where JAX's default device runs everything)."""
     import jax
 
     if e.kind is ElementKind.TRANSFER:
@@ -264,23 +264,29 @@ class ThreadLaneExecutor(Executor):
         self.timeline = Timeline()
         self.history = KernelHistory()
         self.num_devices = max(1, num_devices)
-        self._jax_devices = None           # resolved lazily (jax.devices())
+        # Multi-device schedules pin each lane to one of jax.devices(); a
+        # schedule naming more devices than are visible is refused here
+        # rather than folded onto fewer devices.
+        self._jax_devices = None
+        if self.num_devices > 1:
+            import jax
+            visible = jax.devices()
+            if len(visible) < self.num_devices:
+                raise ValueError(
+                    f"num_devices={self.num_devices} but only "
+                    f"{len(visible)} {visible[0].platform} device(s) are "
+                    f"visible")
+            self._jax_devices = visible[:self.num_devices]
         self._lanes: Dict[int, _LaneWorker] = {}
         self._submitted: List[ComputationalElement] = []
         self._epoch = time.perf_counter()
 
     def jax_device_for(self, element: ComputationalElement):
         """JAX device backing the element's lane; None when single-device
-        (scheduling still works, D2D copies degrade to no-ops)."""
-        if self.num_devices <= 1:
-            return None
+        (JAX's default device runs everything)."""
         if self._jax_devices is None:
-            import jax
-            self._jax_devices = jax.devices()
-        if len(self._jax_devices) <= 1:
             return None
-        dev = element.device if element.device is not None else 0
-        return self._jax_devices[dev % len(self._jax_devices)]
+        return self._jax_devices[element.device or 0]
 
     def host_now(self) -> float:
         return time.perf_counter() - self._epoch

@@ -16,6 +16,7 @@ sub-DAG from the frontier.
 from __future__ import annotations
 
 import itertools
+import sys
 import threading
 import warnings
 from typing import Callable, List, Mapping, Optional, Sequence
@@ -513,14 +514,19 @@ class GrScheduler:
         if self._closed:
             return
         self._closed = True
+        # Called while another exception unwinds (a ``finally`` or
+        # ``__exit__`` after a failure), a failing drain must not mask it.
+        unwinding = sys.exc_info()[1] is not None
         # Paused (preempted) work must drain before workers are stopped.
         self.deadlines.resume_all()
         try:
             self.sync()
         except Exception:
-            pass            # best effort: close from an except path anyway
-        self.executor.shutdown()
-        self.memory.close()
+            if not unwinding:
+                raise
+        finally:
+            self.executor.shutdown()
+            self.memory.close()
 
     def shutdown(self) -> None:
         """Backward-compatible alias for :meth:`close`."""

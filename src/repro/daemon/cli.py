@@ -59,8 +59,9 @@ def build_parser() -> argparse.ArgumentParser:
                        help="job journal path (env REPRO_DAEMON_STORE)")
     serve.add_argument("--workers", type=int, default=2,
                        help="dispatcher threads")
-    serve.add_argument("--devices", type=int, default=2,
-                       help="scheduler device lanes")
+    serve.add_argument("--devices", type=int, default=1,
+                       help="devices the scheduler places work on (at most "
+                            "the number JAX sees)")
     serve.add_argument("--executor", default="threads",
                        choices=["threads", "sim"], help="scheduler executor")
     serve.add_argument("--mem-budget", type=float, default=None,
@@ -127,6 +128,9 @@ def _serve(args) -> int:
     from .policy import AdmissionPolicy
     from .server import DaemonServer
 
+    if args.executor == "threads":
+        from ..compile_cache import enable_compile_cache
+        enable_compile_cache()
     sched_kw = {"num_devices": args.devices,
                 "simulate": args.executor == "sim"}
     if args.mem_budget is not None:

@@ -177,20 +177,28 @@ def noop_job(ctx: JobContext, **params) -> dict:
 def serve_lm_job(ctx: JobContext, *, arch: str = "qwen2_moe_a2_7b",
                  requests: int = 4, prompt_len: int = 16,
                  new_tokens: int = 4, batch_size: int = 2,
-                 seed: int = 0) -> dict:
-    """Daemon-backed serving: run a reduced LM ServingEngine *inside* the
-    resident runtime and pump ``requests`` greedy generations through it.
+                 seed: int = 0, reduced: bool = True) -> dict:
+    """Daemon-backed serving: run an LM ServingEngine *inside* the resident
+    runtime and pump ``requests`` greedy generations through it.
+
+    ``reduced=True`` (the default) serves the small float32 test config;
+    ``reduced=False`` the registered config at its published widths with
+    bfloat16 weights.  Weights come from ``PRNGKey(seed)`` and prompts from
+    ``RandomState(seed)``, so a caller can rebuild both.
 
     This is the out-of-process submit path for ``runtime/serving.py`` — a
     client process gets batched, capture-replayed inference from the shared
     daemon scheduler without linking jax or the model itself."""
     import jax
+    import jax.numpy as jnp
     from ..configs import get_config
     from ..models import init_lm
     from ..runtime.serving import ServingEngine
 
-    cfg = get_config(arch, reduced=True)
-    params = init_lm(jax.random.PRNGKey(int(seed)), cfg)
+    reduced = bool(reduced)
+    cfg = get_config(arch, reduced=reduced)
+    params = init_lm(jax.random.PRNGKey(int(seed)), cfg,
+                     dtype=jnp.float32 if reduced else jnp.bfloat16)
     rng = np.random.RandomState(int(seed))
     with ServingEngine(cfg, params, batch_size=int(batch_size),
                        max_new_tokens=int(new_tokens),

@@ -4,5 +4,6 @@ The paper itself has no kernel-level contribution (its kernels come from
 open-source suites); these are the perf-critical layers of the *framework*:
 flash_attention (blocked online softmax), rwkv6 (WKV recurrence), rmsnorm.
 Each package has kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
-wrapper, interpret-mode fallback off-TPU) and ref.py (pure-jnp oracle).
+wrapper; callers off the TPU pass ``interpret=True``) and ref.py (pure-jnp
+oracle).
 """

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.launch.mesh import make_mesh_for
 from repro.models import init_cache, init_lm
@@ -30,6 +31,7 @@ def main() -> None:
     ap.add_argument("--new-tokens", type=int, default=16)
     ap.add_argument("--model-parallel", type=int, default=2)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     mesh = make_mesh_for(len(jax.devices()), args.model_parallel)

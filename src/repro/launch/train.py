@@ -17,6 +17,7 @@ import time
 import jax
 
 from repro.checkpoint import CheckpointManager
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.data import SyntheticTokenStream
 from repro.launch.mesh import make_mesh_for, make_production_mesh
@@ -43,6 +44,7 @@ def main() -> None:
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--model-parallel", type=int, default=2)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     if args.production_mesh:

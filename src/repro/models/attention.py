@@ -1,6 +1,7 @@
 """Grouped-query attention: full/sliding-window causal, cross, and cached
-decode.  The blocked-softmax compute path dispatches to the Pallas flash
-kernel on TPU (kernels/flash_attention) with a pure-jnp fallback elsewhere.
+decode.  With ``use_flash`` the blocked-softmax path runs the Pallas flash
+kernel (kernels/flash_attention), which compiles for the TPU only; without
+it, a pure-jnp blocked softmax.
 """
 from __future__ import annotations
 

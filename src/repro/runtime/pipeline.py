@@ -25,7 +25,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -78,8 +77,8 @@ def pipeline_apply(stage_fn: Callable, mesh: Mesh, axis: str = "pod"):
 
     # P(axis) acts as a prefix spec for the whole parameter pytree: every
     # leaf is sharded on its leading (stage) dim; activations replicated.
-    return shard_map(ranked, mesh=mesh, in_specs=(P(axis), P()),
-                     out_specs=P(), check_rep=False)
+    return jax.shard_map(ranked, mesh=mesh, in_specs=(P(axis), P()),
+                         out_specs=P(), check_vma=False)
 
 
 def pipeline_loss_fn(stage_fn: Callable, loss_tail: Callable, mesh: Mesh,

@@ -5,8 +5,20 @@ CI runs on every push (``scripts/ci_fast.sh`` / ``-m "not slow"``).  Slow
 standalone tests carry an explicit ``@pytest.mark.slow``; for the
 arch-parametrized model tests the heavyweight configs are marked here so
 the parametrize decorators stay readable.
+
+The suite runs on the CPU backend.  XLA's host platform is split into four
+virtual devices (set before any test initializes a backend) so that the
+real executor's multi-device schedules have a device per lane; the
+executor refuses a schedule that names more devices than are visible.
 """
+import os
+
 import pytest
+
+_xla_flags = os.environ.get("XLA_FLAGS", "")
+if "xla_force_host_platform_device_count" not in _xla_flags:
+    os.environ["XLA_FLAGS"] = (
+        f"{_xla_flags} --xla_force_host_platform_device_count=4".strip())
 
 # Reduced configs that still take many seconds per test to jit on CPU.
 _SLOW_ARCHS = ("seamless_m4t_medium", "gemma3_12b")

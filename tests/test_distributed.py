@@ -33,7 +33,7 @@ def test_sharded_train_step_matches_single_device():
     res = run_sub("""
         import json
         import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
         from repro.configs import get_config
         from repro.models import init_lm
         from repro.optim import AdamW
@@ -43,7 +43,8 @@ def test_sharded_train_step_matches_single_device():
         from repro.models.config import ShapeCell
 
         cfg = get_config("qwen3_32b", reduced=True)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         opt = AdamW(lr=1e-3)
         params = init_lm(jax.random.PRNGKey(0), cfg)
         state = TrainState(params, opt.init(params))
@@ -92,12 +93,11 @@ def test_compressed_psum_error_feedback():
         mesh = jax.make_mesh((8,), ("data",))
         x = np.random.RandomState(0).randn(8, 64, 256).astype(np.float32)
 
-        from jax.experimental.shard_map import shard_map
         def body(xs, errs):
             g, e = compressed_psum({"g": xs}, {"g": errs}, "data")
             return g["g"], e["g"]
 
-        f = jax.jit(shard_map(body, mesh=mesh,
+        f = jax.jit(jax.shard_map(body, mesh=mesh,
                               in_specs=(P("data"), P("data")),
                               out_specs=(P("data"), P("data"))))
         errs = jnp.zeros_like(x)
@@ -154,12 +154,14 @@ def test_dryrun_cell_compiles_on_toy_mesh():
     res = run_sub("""
         import json
         import jax
+        from jax.sharding import AxisType
         from repro.configs import get_config
         from repro.launch.specs import build_cell
         from repro.launch.hlostats import analyze_hlo
         from repro.models.config import ShapeCell
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         cfg = get_config("gemma3_12b", reduced=True)
         cell = ShapeCell("t", 64, 8, "train")
         low = build_cell(cfg, cell, mesh)

@@ -1,5 +1,8 @@
 """Pallas kernel validation (interpret mode) against pure-jnp oracles.
 
+The wrappers compile for the TPU by default; every call here passes
+``interpret=True`` explicitly, and a call without it off the TPU raises.
+
 Each kernel is swept over shapes/dtypes (explicit grid + hypothesis-driven
 random shapes) and asserted allclose to its ref.py oracle.
 """
@@ -38,7 +41,7 @@ def test_flash_attention_matches_ref(B, S, H, Hkv, hd, window, softcap,
     k = jax.random.normal(ks[1], (B, S, Hkv, hd), dtype)
     v = jax.random.normal(ks[2], (B, S, Hkv, hd), dtype)
     got = flash_attention(q, k, v, window=window, softcap=softcap,
-                          block_q=64, block_k=64)
+                          block_q=64, block_k=64, interpret=True)
     ref = attention_ref(q, k, v, window=window, softcap=softcap)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(ref, np.float32), **_tol(dtype))
@@ -57,7 +60,8 @@ def test_flash_attention_property(S, B, hd, g, windowed):
     k = jax.random.normal(ks[1], (B, S, Hkv, hd), jnp.float32)
     v = jax.random.normal(ks[2], (B, S, Hkv, hd), jnp.float32)
     window = S // 2 if windowed else 0
-    got = flash_attention(q, k, v, window=window, block_q=32, block_k=32)
+    got = flash_attention(q, k, v, window=window, block_q=32, block_k=32,
+                          interpret=True)
     ref = attention_ref(q, k, v, window=window)
     np.testing.assert_allclose(got, ref, rtol=3e-5, atol=3e-5)
 
@@ -72,9 +76,25 @@ def test_flash_matches_model_chunked_path():
     q = jax.random.normal(ks[0], (2, 256, 4, 32), jnp.float32)
     k = jax.random.normal(ks[1], (2, 256, 2, 32), jnp.float32)
     v = jax.random.normal(ks[2], (2, 256, 2, 32), jnp.float32)
-    a = flash_attention(q, k, v, block_q=64, block_k=64)
+    a = flash_attention(q, k, v, block_q=64, block_k=64, interpret=True)
     b = _sdpa_chunked(Cfg, q, k, v, q_chunk=64, kv_chunk=64)
     np.testing.assert_allclose(a.reshape(2, 256, -1), b, rtol=3e-5, atol=3e-5)
+
+
+@pytest.mark.parametrize("call", ["flash_attention", "rmsnorm", "wkv6"])
+def test_wrappers_refuse_to_run_compiled_off_the_tpu(call):
+    """Interpret mode is the caller's choice, never inferred: without it a
+    wrapper compiles for the TPU, and the CPU backend refuses that."""
+    assert jax.default_backend() != "tpu"
+    x = jnp.ones((1, 128, 2, 64), jnp.float32)
+    with pytest.raises(ValueError, match="interpret"):
+        if call == "flash_attention":
+            flash_attention(x, x, x, block_q=64, block_k=64)
+        elif call == "rmsnorm":
+            rmsnorm(x, jnp.ones((64,), jnp.float32))
+        else:
+            wkv6(x, x, x, x, jnp.ones((2, 64), jnp.float32),
+                 jnp.zeros((1, 2, 64, 64), jnp.float32), chunk=64)
 
 
 # ---------------------------------------------------------------- rwkv6
@@ -93,7 +113,7 @@ def test_wkv6_matches_ref(B, T, H, hd, chunk, dtype):
     u = jax.random.normal(ks[4], (H, hd), dtype) * 0.3
     s0 = jax.random.normal(ks[5], (B, H, hd, hd), jnp.float32) * 0.1
 
-    y, sT = wkv6(r, k, v, w, u, s0, chunk=chunk)
+    y, sT = wkv6(r, k, v, w, u, s0, chunk=chunk, interpret=True)
     flat = lambda x: jnp.swapaxes(x, 1, 2).reshape(B * H, T, hd)
     y_ref, sT_ref = wkv6_ref(flat(r), flat(k), flat(v), flat(w),
                              jnp.tile(u[None], (B, 1, 1)).reshape(B * H, hd),
@@ -113,11 +133,11 @@ def test_wkv6_state_carry_composes():
     w = jax.nn.sigmoid(jax.random.normal(ks[3], (B, T, H, hd))) * 0.5 + 0.4
     u = jax.random.normal(ks[4], (H, hd)) * 0.3
     s0 = jnp.zeros((B, H, hd, hd), jnp.float32)
-    y_full, s_full = wkv6(r, k, v, w, u, s0, chunk=16)
+    y_full, s_full = wkv6(r, k, v, w, u, s0, chunk=16, interpret=True)
     y1, s_mid = wkv6(r[:, :32], k[:, :32], v[:, :32], w[:, :32], u, s0,
-                     chunk=16)
+                     chunk=16, interpret=True)
     y2, s_end = wkv6(r[:, 32:], k[:, 32:], v[:, 32:], w[:, 32:], u, s_mid,
-                     chunk=16)
+                     chunk=16, interpret=True)
     np.testing.assert_allclose(jnp.concatenate([y1, y2], axis=1), y_full,
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(s_end, s_full, rtol=1e-5, atol=1e-5)
@@ -141,7 +161,7 @@ def test_wkv6_matches_model_layer():
     r, k, v, g, w = R._projections(p, x, x_prev, x.dtype)
     resh = lambda t: t.reshape(B, S, H, hd)
     y_k, _ = wkv6(resh(r), resh(k), resh(v), resh(w.astype(x.dtype)),
-                  p["bonus_u"], state["wkv"], chunk=16)
+                  p["bonus_u"], state["wkv"], chunk=16, interpret=True)
     y_k = R._group_norm(y_k.reshape(B * S, d), p["ln_x_scale"], H
                         ).reshape(B, S, d)
     y_k = y_k * jax.nn.silu(g)
@@ -157,7 +177,7 @@ def test_rmsnorm_matches_ref(shape, dtype):
     x = jax.random.normal(ks[0], shape, dtype)
     scale = jax.random.normal(ks[1], (shape[-1],), dtype) * 0.1 + 1.0
     np.testing.assert_allclose(
-        np.asarray(rmsnorm(x, scale), np.float32),
+        np.asarray(rmsnorm(x, scale, interpret=True), np.float32),
         np.asarray(rmsnorm_ref(x, scale), np.float32), **_tol(dtype))
 
 
@@ -166,7 +186,7 @@ def test_rmsnorm_matches_ref(shape, dtype):
 def test_rmsnorm_property(rows, d):
     x = jax.random.normal(jax.random.PRNGKey(rows), (rows, d), jnp.float32)
     scale = jnp.ones((d,))
-    got = rmsnorm(x, scale)
+    got = rmsnorm(x, scale, interpret=True)
     np.testing.assert_allclose(got, rmsnorm_ref(x, scale), rtol=2e-5,
                                atol=2e-5)
     # invariant: output row RMS ~= 1 for unit scale

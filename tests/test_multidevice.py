@@ -9,6 +9,7 @@ from repro.benchsuite.multidevice import (build_locality_heavy,
                                           build_task_parallel)
 from repro.core import (ElementKind, SimExecutor, SimHardware, const, inout,
                         make_scheduler, out)
+from repro.core.frontend import function
 
 
 # ----------------------------------------------------------------------
@@ -245,5 +246,34 @@ def test_real_executor_task_parallel_chains():
             outs.append(x)
         for b, o in enumerate(outs):
             np.testing.assert_allclose(np.asarray(o), b + 3)
+    finally:
+        s.shutdown()
+
+
+def test_real_executor_refuses_more_devices_than_visible():
+    """No silent fold-down: a schedule over more devices than JAX sees
+    fails at construction instead of running everything on fewer."""
+    n = len(jax.devices())
+    with pytest.raises(ValueError, match=f"num_devices={n + 1}"):
+        make_scheduler("parallel", num_devices=n + 1)
+
+
+def test_real_executor_computes_on_each_scheduled_device():
+    """Round-robin over two devices: each independent kernel's output is
+    a jax.Array committed to the device its lane is pinned to."""
+    s = make_scheduler("parallel", num_devices=2, placement="round-robin")
+    dbl = function(jax.jit(lambda a, _: a * 2), modes=("const", "out"),
+                   name="dbl", scheduler=s)
+    try:
+        outs = []
+        for i in range(4):
+            x = s.array(np.full(16, float(i), np.float32), name=f"x{i}")
+            y = s.array(np.zeros(16, np.float32), name=f"y{i}")
+            dbl(x, y)
+            outs.append(y)
+        for i, y in enumerate(outs):
+            np.testing.assert_allclose(np.asarray(y), 2.0 * i)
+        used = {d for y in outs for d in y.device.devices()}
+        assert used == set(jax.devices()[:2])
     finally:
         s.shutdown()

@@ -70,6 +70,38 @@ def test_close_drains_inflight_work_first():
     assert not _lane_threads()
 
 
+def _failing_scheduler():
+    s = make_scheduler("parallel")
+    x = s.array(np.ones(8, np.float32), name="x")
+    y = s.array(np.zeros(8, np.float32), name="y")
+
+    def broken(a, b):
+        raise FloatingPointError("kernel failed on the device")
+
+    s._launch(broken, [const(x), out(y)], name="broken")
+    return s
+
+
+def test_close_surfaces_a_failing_element():
+    """An element whose output is never read still fails the run: close()
+    re-raises what its drain hit, after stopping the workers."""
+    s = _failing_scheduler()
+    with pytest.raises(FloatingPointError, match="failed on the device"):
+        s.close()
+    assert s._closed
+    assert not _lane_threads()
+
+
+def test_close_while_unwinding_keeps_the_original_error():
+    s = _failing_scheduler()
+    with pytest.raises(KeyError, match="first"):
+        try:
+            raise KeyError("first")
+        finally:
+            s.close()
+    assert not _lane_threads()
+
+
 def test_close_releases_disk_tier_spool_directory():
     s = make_scheduler("parallel", simulate=True,
                        memory_budget=8 * 1024, spill_tiers=[DiskTier()])
