@@ -29,7 +29,7 @@ import numpy as np
 
 from .element import ComputationalElement, ElementKind, ElementState
 from .history import KernelHistory
-from .timeline import Timeline
+from .timeline import Timeline, span
 
 
 class Executor:
@@ -113,26 +113,36 @@ class Executor:
 # Real execution: threads as lanes, JAX async dispatch underneath
 # ======================================================================
 
+def _put(x, jdev):
+    """Copy ``x`` onto ``jdev`` and wait for it: the two child spans of a
+    lane's copy."""
+    import jax
+
+    with span("dispatch"):
+        val = jax.device_put(x, jdev)
+    with span("block"):
+        if hasattr(val, "block_until_ready"):
+            val.block_until_ready()
+    return val
+
+
 def _run_device_element(e: ComputationalElement, jdev=None):
     """Execute a kernel/transfer element against its ManagedArray args.
 
     ``jdev`` is the JAX device the element's lane is pinned to (None on a
-    single-device executor, where JAX's default device runs everything)."""
+    single-device executor, where JAX's default device runs everything).
+    The call (``gr:dispatch``) and the wait for its result (``gr:block``)
+    are spans of their own."""
     import jax
 
     if e.kind is ElementKind.TRANSFER:
         ma = e.args[0].array
-        val = jax.device_put(np.asarray(ma.host), jdev)
-        val.block_until_ready()
-        ma.set_physical_device(val)
+        ma.set_physical_device(_put(np.asarray(ma.host), jdev))
         return
 
     if e.kind is ElementKind.D2D:
         ma = e.args[0].array
-        val = jax.device_put(ma.device_value(), jdev)
-        if hasattr(val, "block_until_ready"):
-            val.block_until_ready()
-        ma.set_physical_device(val)
+        ma.set_physical_device(_put(ma.device_value(), jdev))
         return
 
     if e.kind is ElementKind.EVICT:
@@ -141,22 +151,21 @@ def _run_device_element(e: ComputationalElement, jdev=None):
         if tier is not None and tier.location == "device":
             # Peer-device spill: a D2D copy onto the tier's target device
             # (the lane — and jdev — belong to the target, like any D2D).
-            val = jax.device_put(ma.device_value(), jdev)
-            if hasattr(val, "block_until_ready"):
-                val.block_until_ready()
-            ma.set_physical_device(val)
+            ma.set_physical_device(_put(ma.device_value(), jdev))
             return
         if tier is not None:
             # Host-side tier: store/encode the payload (compressed bytes,
             # spool file), then release the device buffer.
-            tier.spill(ma)
+            with span("dispatch"):
+                tier.spill(ma)
             ma.set_physical_device(None)
             return
         # Flat budget spill: write the device copy back to the host buffer
         # when it was the only valid one, then actually release the device
         # buffer (dropping the reference frees the backing device memory).
         if e.config.get("writeback", True) and ma.device is not None:
-            np.copyto(ma.host, np.asarray(ma.device))
+            with span("block"):
+                np.copyto(ma.host, np.asarray(ma.device))
         ma.set_physical_device(None)
         return
 
@@ -164,30 +173,36 @@ def _run_device_element(e: ComputationalElement, jdev=None):
         # Bring a tier-spilled block back: the tier decodes/reads the
         # payload (refreshing ma.host) and the copy engine uploads it.
         ma = e.args[0].array
-        val = jax.device_put(np.asarray(e.tier.reload(ma)), jdev)
-        val.block_until_ready()
-        ma.set_physical_device(val)
+        ma.set_physical_device(_put(np.asarray(e.tier.reload(ma)), jdev))
         return
 
-    inputs = [a.array.device_value() for a in e.args]
-    if jdev is not None:
-        # Commit every input to the lane's device so XLA runs the kernel
-        # there (device_put is a no-op for values already resident).
-        inputs = [jax.device_put(x, jdev) for x in inputs]
-    result = e.fn(*inputs)
+    with span("dispatch"):
+        inputs = [a.array.device_value() for a in e.args]
+        if jdev is not None:
+            # Commit every input to the lane's device so XLA runs the
+            # kernel there (device_put is a no-op for values already
+            # resident).
+            inputs = [jax.device_put(x, jdev) for x in inputs]
+        result = e.fn(*inputs)
     writable = [a.array for a in e.args if a.mode.writes]
-    if writable:
-        outs = result if isinstance(result, (tuple, list)) else (result,)
-        if len(outs) != len(writable):
-            raise ValueError(
-                f"kernel {e.name}: returned {len(outs)} outputs for "
-                f"{len(writable)} writable args")
-        for ma, val in zip(writable, outs):
-            if hasattr(val, "block_until_ready"):
-                val.block_until_ready()
-            ma.set_physical_device(val)
-    elif result is not None and hasattr(result, "block_until_ready"):
-        result.block_until_ready()
+    with span("block"):
+        if writable:
+            outs = result if isinstance(result, (tuple, list)) else (result,)
+            if len(outs) != len(writable):
+                raise ValueError(
+                    f"kernel {e.name}: returned {len(outs)} outputs for "
+                    f"{len(writable)} writable args")
+            for ma, val in zip(writable, outs):
+                if hasattr(val, "block_until_ready"):
+                    val.block_until_ready()
+                ma.set_physical_device(val)
+        elif result is not None and hasattr(result, "block_until_ready"):
+            result.block_until_ready()
+
+
+# The span of a lane element's body, by the element's kind.
+_BODY_SPAN = {ElementKind.TRANSFER: "h2d", ElementKind.D2D: "d2d",
+              ElementKind.EVICT: "evict", ElementKind.RELOAD: "reload"}
 
 
 class _LaneWorker(threading.Thread):
@@ -205,24 +220,29 @@ class _LaneWorker(threading.Thread):
                 return
             element, waits = item
             try:
-                while waits:        # pop: no loop variable may outlive the
-                    waits.pop().done_event.wait()   # wait (see finally below)
-                # Element-boundary preemption: a paused element blocks its
-                # lane *in place* (FIFO order is a dependency carrier — the
-                # queue must never be reordered) until the deadline monitor
-                # resumes it.  A gate published after this check simply
-                # means the element already started: running work is never
-                # interrupted.
-                gate = element.pause_gate
-                if gate is not None:
-                    gate.wait()
+                with span("lane_wait", uid=element.uid, lane=self.lane_id):
+                    while waits:    # pop: no loop variable may outlive the
+                        waits.pop().done_event.wait()  # wait (see finally)
+                    # Element-boundary preemption: a paused element blocks
+                    # its lane *in place* (FIFO order is a dependency
+                    # carrier — the queue must never be reordered) until
+                    # the deadline monitor resumes it.  A gate published
+                    # after this check simply means the element already
+                    # started: running work is never interrupted.
+                    gate = element.pause_gate
+                    if gate is not None:
+                        gate.wait()
                 element.state = ElementState.RUNNING
                 pre = self.executor.pre_exec
                 if pre is not None:
                     pre(element)
                 t0 = self.executor.host_now()
-                _run_device_element(element,
-                                    self.executor.jax_device_for(element))
+                with span(_BODY_SPAN.get(element.kind, "kernel"),
+                          uid=element.uid, lane=self.lane_id,
+                          nbytes=element.transfer_bytes,
+                          device=element.device or 0):
+                    _run_device_element(
+                        element, self.executor.jax_device_for(element))
                 t1 = self.executor.host_now()
                 post = self.executor.post_exec
                 if post is not None:

@@ -45,6 +45,7 @@ import numpy as np
 from .element import AccessMode, Arg, DEFAULT_TENANT
 from .managed import ManagedArray
 from .scheduler import GrScheduler, make_scheduler
+from .timeline import span
 
 _FN_IDS = itertools.count()
 
@@ -353,35 +354,39 @@ class GrFunction:
         instead.  ``**overrides`` accepts the same keys as
         :meth:`with_options`, scoped to this call only.
         """
-        gf = self.with_options(**overrides) if overrides else self
-        sched = gf._resolve_scheduler(scheduler, arrays)
-        n = len(gf.modes)
-        if len(arrays) > n:
-            raise TypeError(f"{gf.name}: takes at most {n} argument(s), "
-                            f"got {len(arrays)}")
-        allocated = []
-        if len(arrays) < n:
-            out_positions = gf._out_positions()
-            full = list(arrays)
-            for pos in range(len(arrays), n):
-                if gf.modes[pos] is not AccessMode.OUT:
-                    raise TypeError(
-                        f"{gf.name}: argument {pos} "
-                        f"('{gf.modes[pos].value}') must be supplied — only "
-                        f"trailing 'out' arguments can be runtime-allocated")
-                ma = gf._allocate(pos, out_positions.index(pos), arrays,
-                                  sched, gf.name)
-                allocated.append(ma)
-                full.append(ma)
-            arrays = tuple(full)
-        args = tuple(Arg(a, m) for a, m in zip(arrays, gf.modes))
-        element = sched._launch(
-            gf.fn, args, name=gf.name, cost_s=gf.cost_s, tune=gf.tune,
-            priority=gf.priority, tenant=gf.tenant, device=gf.device,
-            deadline_s=gf.deadline_s, fn_key=gf.fid, **gf.config)
-        if allocated:
-            return allocated[0] if len(allocated) == 1 else tuple(allocated)
-        return element
+        with span("launch") as sp:
+            gf = self.with_options(**overrides) if overrides else self
+            sched = gf._resolve_scheduler(scheduler, arrays)
+            n = len(gf.modes)
+            if len(arrays) > n:
+                raise TypeError(f"{gf.name}: takes at most {n} argument(s), "
+                                f"got {len(arrays)}")
+            allocated = []
+            if len(arrays) < n:
+                out_positions = gf._out_positions()
+                full = list(arrays)
+                for pos in range(len(arrays), n):
+                    if gf.modes[pos] is not AccessMode.OUT:
+                        raise TypeError(
+                            f"{gf.name}: argument {pos} "
+                            f"('{gf.modes[pos].value}') must be supplied — "
+                            f"only trailing 'out' arguments can be "
+                            f"runtime-allocated")
+                    ma = gf._allocate(pos, out_positions.index(pos), arrays,
+                                      sched, gf.name)
+                    allocated.append(ma)
+                    full.append(ma)
+                arrays = tuple(full)
+            args = tuple(Arg(a, m) for a, m in zip(arrays, gf.modes))
+            element = sched._launch(
+                gf.fn, args, name=gf.name, cost_s=gf.cost_s, tune=gf.tune,
+                priority=gf.priority, tenant=gf.tenant, device=gf.device,
+                deadline_s=gf.deadline_s, fn_key=gf.fid, **gf.config)
+            sp.set_metadata(uid=element.uid)
+            if allocated:
+                return (allocated[0] if len(allocated) == 1
+                        else tuple(allocated))
+            return element
 
     def __repr__(self) -> str:  # pragma: no cover - debugging aid
         modes = ",".join(m.value for m in self.modes)

@@ -33,7 +33,7 @@ from .managed import ManagedArray
 from .memory import Budget, MemoryManager
 from .streams import NewStreamPolicy, ParentStreamPolicy, StreamManager
 from .submission import SubmissionPipeline
-from .timeline import Timeline
+from .timeline import Timeline, span
 
 # A replayed plan is submitted with a single reduced launch overhead — the
 # cudaGraphLaunch analogue: roughly one hardware kernel-launch, however many
@@ -175,9 +175,12 @@ class GrScheduler:
             "GrScheduler.launch is deprecated: declare the kernel once with "
             "repro.api.function(fn, modes=...) and call the GrFunction "
             "directly", DeprecationWarning, stacklevel=2)
-        return self._launch(fn, args, name=name, cost_s=cost_s, tune=tune,
-                            priority=priority, tenant=tenant,
-                            deadline_s=deadline_s, **config)
+        with span("launch") as sp:
+            e = self._launch(fn, args, name=name, cost_s=cost_s, tune=tune,
+                             priority=priority, tenant=tenant,
+                             deadline_s=deadline_s, **config)
+            sp.set_metadata(uid=e.uid)
+        return e
 
     def _launch(self, fn: Optional[Callable], args: Sequence[Arg], *,
                 name: str = "", cost_s: float = 0.0,
@@ -304,8 +307,10 @@ class GrScheduler:
         # (priority inversion).  wait() is a pure completion-event wait and
         # the post-wait retire/release below are idempotent under the
         # re-acquired lock, so a concurrent sync() racing us is harmless.
-        for p in waits:
-            self.executor.wait(p)
+        if waits:
+            with span("host_wait", uid=e.uid):
+                for p in waits:
+                    self.executor.wait(p)
         with self.pipeline:
             self.dag.retire(e)
             for p in e.parents:
@@ -336,7 +341,8 @@ class GrScheduler:
                 return
 
     def host_read(self, ma: ManagedArray) -> None:
-        self._sync_and_localize(ma, writes=False)
+        with span("host_read"):
+            self._sync_and_localize(ma, writes=False)
 
     def host_write(self, ma: ManagedArray) -> None:
         with self.pipeline:
@@ -347,7 +353,8 @@ class GrScheduler:
                 # episode to eager execution when the array is plan-bound.
                 self._capture.note_host_write(ma)
         # D2H before the write: read-modify-write safety for partial updates.
-        self._sync_and_localize(ma, writes=True)
+        with span("host_write"):
+            self._sync_and_localize(ma, writes=True)
 
     def _d2h(self, ma: ManagedArray) -> None:
         ex = self.executor
@@ -358,7 +365,8 @@ class GrScheduler:
             ex.timeline.record(-1, f"d2h_{ma.name}", "d2h", None, t0, ex.host_time)
         else:
             t0 = ex.host_now()
-            ma.host = np.asarray(ma.device)
+            with span("d2h", nbytes=ma.nbytes):
+                ma.host = np.asarray(ma.device)
             ex.timeline.record(-1, f"d2h_{ma.name}", "d2h", None, t0, ex.host_now())
         ma.host_valid = True
 
@@ -379,7 +387,8 @@ class GrScheduler:
                                None, t0, ex.host_time)
         else:
             t0 = ex.host_now()
-            tier.reload(ma)     # refreshes ma.host, drops the payload
+            with span("tier_restore", nbytes=ma.nbytes):
+                tier.reload(ma)     # refreshes ma.host, drops the payload
             ex.timeline.record(-1, f"tier_{tier.name}_{ma.name}", "d2h",
                                None, t0, ex.host_now())
         self.memory.note_tier_to_host(ma)
@@ -437,31 +446,35 @@ class GrScheduler:
     # ------------------------------------------------------------------
     def sync(self) -> None:
         """Full barrier: host waits for every in-flight computation."""
-        if self.executor.concurrent_waits:
-            # Drain outside the pipeline lock (same priority-inversion guard
-            # as _sync_against): one tenant's barrier must not freeze other
-            # tenants' launches while device work finishes.  The locked
-            # wait_all afterwards is near-instant unless new work raced in
-            # during the drain — which the barrier then also covers.
+        with span("sync"):
+            if self.executor.concurrent_waits:
+                # Drain outside the pipeline lock (same priority-inversion
+                # guard as _sync_against): one tenant's barrier must not
+                # freeze other tenants' launches while device work
+                # finishes.  The locked wait_all afterwards is near-instant
+                # unless new work raced in during the drain — which the
+                # barrier then also covers.
+                with self.pipeline:
+                    if self._capture is not None:
+                        self._capture.note_host_sync(None)
+                    pending = list(self._elements)
+                with span("host_wait"):
+                    for e in pending:
+                        self.executor.wait(e)
             with self.pipeline:
-                if self._capture is not None:
+                if (self._capture is not None
+                        and not self.executor.concurrent_waits):
                     self._capture.note_host_sync(None)
-                pending = list(self._elements)
-            for e in pending:
-                self.executor.wait(e)
-        with self.pipeline:
-            if self._capture is not None and not self.executor.concurrent_waits:
-                self._capture.note_host_sync(None)
-            self.executor.wait_all()
-            self.dag.retire_all()
-            for e in self._elements:
-                self.streams.release(e)
-            # Retired elements can never need another release; keeping them
-            # made every later sync re-walk (and re-release) the whole
-            # history — unbounded memory and O(n^2) cost in long-running
-            # serving loops.
-            self._elements.clear()
-            self._host_log.clear()
+                self.executor.wait_all()
+                self.dag.retire_all()
+                for e in self._elements:
+                    self.streams.release(e)
+                # Retired elements can never need another release; keeping
+                # them made every later sync re-walk (and re-release) the
+                # whole history — unbounded memory and O(n^2) cost in
+                # long-running serving loops.
+                self._elements.clear()
+                self._host_log.clear()
 
     def verify(self, plans: bool = True) -> None:
         """Run the happens-before verifier (``repro.analysis``) over the

@@ -29,6 +29,7 @@ from typing import TYPE_CHECKING, Iterable, Optional, Sequence, Set
 
 from .element import (Arg, ComputationalElement, DEFAULT_TENANT, ElementKind,
                       inout)
+from .timeline import span
 
 if TYPE_CHECKING:  # pragma: no cover
     from .scheduler import GrScheduler
@@ -48,7 +49,8 @@ class SubmissionPipeline:
 
     # -- critical section ------------------------------------------------
     def __enter__(self) -> "SubmissionPipeline":
-        self._lock.acquire()
+        with span("lock"):
+            self._lock.acquire()
         self._seen_threads.add(threading.get_ident())
         return self
 
@@ -66,15 +68,17 @@ class SubmissionPipeline:
         # cross-device inputs get D2D copies before the kernel is added.
         # A caller-pinned device (GrFunction ``with_options(device=...)``)
         # bypasses the placement policy but is clamped to the device count.
-        if e.device is None:
-            e.device = sched.streams.place(e, sched.executor.is_done)
-        else:
-            e.device = min(max(0, int(e.device)), sched.num_devices - 1)
+        with span("place"):
+            if e.device is None:
+                e.device = sched.streams.place(e, sched.executor.is_done)
+            else:
+                e.device = min(max(0, int(e.device)), sched.num_devices - 1)
         # Reserve the element's working set before anything lands on the
         # device: under budget pressure this synthesizes DAG-ordered EVICT
         # elements for LRU victims (spill D2H first, reload H2D after — the
         # copy engines see them in that order).
-        self.reserve(e)
+        with span("reserve"):
+            self.reserve(e)
         # Tier-resident read args (spilled to compressed host / disk) must
         # come back regardless of ``auto_prefetch``: the fault-driven
         # single-device mode can read a *host-valid* array in place, but a
@@ -88,13 +92,15 @@ class SubmissionPipeline:
         # never localized (migrate() only moves device-owned copies), so the
         # upload is forced regardless of the flag.
         if sched.auto_prefetch or sched.num_devices > 1:
-            self.prefetch(e.args, e.device, priority=e.priority,
-                          tenant=e.tenant, deadline_s=e.deadline_s,
-                          deadline_t=e.deadline_t)
+            with span("prefetch"):
+                self.prefetch(e.args, e.device, priority=e.priority,
+                              tenant=e.tenant, deadline_s=e.deadline_s,
+                              deadline_t=e.deadline_t)
         if sched.num_devices > 1:
-            self.migrate(e.args, e.device, priority=e.priority,
-                         tenant=e.tenant, deadline_s=e.deadline_s,
-                         deadline_t=e.deadline_t)
+            with span("migrate"):
+                self.migrate(e.args, e.device, priority=e.priority,
+                             tenant=e.tenant, deadline_s=e.deadline_s,
+                             deadline_t=e.deadline_t)
         self.schedule(e)
 
     def reserve(self, e: ComputationalElement,
@@ -297,22 +303,26 @@ class SubmissionPipeline:
     def schedule(self, e: ComputationalElement) -> None:
         """DAG insert + lane assignment + submission (parallel policy)."""
         sched = self.sched
-        # Idempotent deadline stamp: kernels arrive tagged from _launch,
-        # auto children carry inherited deadline_t; both still register
-        # with the monitor here (direct schedule() callers get stamped).
-        sched.deadlines.tag(e)
-        sched.executor.host_overhead(sched.launch_overhead_s)
-        if sched.sanitizer is not None:
-            sched.sanitizer.on_schedule(e)
-        sched.dag.add(e)
-        lane, events = sched.streams.assign(e, sched.executor.is_done)
-        sched.executor.submit(e, lane.lane_id, events)
-        sched._elements.append(e)
-        self.submissions += 1
-        # Submission-time deadline-risk check (may preempt queued bulk work).
-        sched.deadlines.on_submit(e)
-        if sched._capture is not None:
-            sched._capture.trace(e)
+        with span("schedule", uid=e.uid) as sp:
+            # Idempotent deadline stamp: kernels arrive tagged from
+            # _launch, auto children carry inherited deadline_t; both still
+            # register with the monitor here (direct schedule() callers get
+            # stamped).
+            sched.deadlines.tag(e)
+            sched.executor.host_overhead(sched.launch_overhead_s)
+            if sched.sanitizer is not None:
+                sched.sanitizer.on_schedule(e)
+            sched.dag.add(e)
+            lane, events = sched.streams.assign(e, sched.executor.is_done)
+            sp.set_metadata(lane=lane.lane_id)
+            sched.executor.submit(e, lane.lane_id, events)
+            sched._elements.append(e)
+            self.submissions += 1
+            # Submission-time deadline-risk check (may preempt queued bulk
+            # work).
+            sched.deadlines.on_submit(e)
+            if sched._capture is not None:
+                sched._capture.trace(e)
 
     def serial(self, e: ComputationalElement) -> None:
         """Original GrCUDA behaviour: blocking, in-order, single lane, no

@@ -8,15 +8,57 @@ the timeline we compute the paper's four overlap metrics:
 * **CC** — fraction of computation time overlapped with other computation;
 * **TOT** — fraction of device-busy time where ≥2 device tasks overlap,
   overlap intervals counted once (union semantics).
+
+``Timeline.record`` keeps these intervals in memory, on the host's
+``perf_counter`` clock.  ``span`` puts the real executor's work into a JAX
+profiler trace as well, on the clock of the device's own events: named
+``gr:<stage>``, with the element's ``uid`` (and ``lane``, ``nbytes``,
+``device`` where they apply) as event stats, so a launch links to the
+elements it scheduled and to their lane spans.
 """
 from __future__ import annotations
 
+import sys
 import threading
 from dataclasses import dataclass, field
 from typing import Dict, Iterable, List, Optional, Tuple
 
 
 _NAN = float("nan")
+
+# Prefix of every runtime span in a profiler trace.
+SPAN_PREFIX = "gr:"
+
+
+class _NoSpan:
+    """What ``span`` returns while no profiler trace is running."""
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def set_metadata(self, **meta) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+def span(name: str, **meta):
+    """A ``gr:<name>`` span in the JAX profiler's trace, with ``meta`` as its
+    event stats; a no-op context unless a trace is running.
+
+    ``name`` is a fixed literal; per-instance data goes in ``meta``.  JAX is
+    never imported here: a trace can only be running once JAX is loaded, so
+    a process that has not loaded it (one that only simulates) gets the
+    no-op."""
+    prof = sys.modules.get("jax.profiler")
+    if prof is None or not prof.TraceAnnotation.is_enabled():
+        return _NO_SPAN
+    return prof.TraceAnnotation(SPAN_PREFIX + name, **meta)
 
 
 @dataclass(frozen=True)
@@ -276,19 +318,6 @@ class Timeline:
         comp = _union([(s.t0, s.t1) for s in self.spans
                        if s.kind == "compute"])
         return _measure(ru) - _measure(_intersect(ru, comp))
-
-    def per_lane(self) -> Dict[int, List[Span]]:
-        lanes: Dict[int, List[Span]] = {}
-        for s in self.device_spans():
-            lanes.setdefault(s.lane if s.lane is not None else -1, []).append(s)
-        return lanes
-
-    def critical_path(self) -> float:
-        """Longest chain end-to-end (lower bound on any schedule)."""
-        return self.makespan  # refined bound computed by benchmarks from DAG
-
-    def to_rows(self) -> List[dict]:
-        return [s.__dict__ | {"dur": s.dur} for s in self.spans]
 
     def to_chrome_trace(self, path: str) -> None:
         """Export as a Chrome trace (chrome://tracing / Perfetto): one row
