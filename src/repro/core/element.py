@@ -161,6 +161,10 @@ class ComputationalElement:
     # different declarations never share one.  ``None`` for legacy
     # ``scheduler.launch`` call sites; capture/replay keys plans by it.
     fn_key: Optional[int] = None
+    # Argument positions whose output the lane starts copying to the host
+    # as soon as the kernel is dispatched (the scheduler's read-ahead
+    # predictor marks them; the simulator ignores the mark).
+    read_ahead: Tuple[int, ...] = ()
 
     # Backing tier driving this EVICT/RELOAD (runtime object, not part of
     # the structural signature — capture encodes the tier *name* in

@@ -176,6 +176,8 @@ def _run_device_element(e: ComputationalElement, jdev=None):
         ma.set_physical_device(_put(np.asarray(e.tier.reload(ma)), jdev))
         return
 
+    writable = [(pos, a.array) for pos, a in enumerate(e.args)
+                if a.mode.writes]
     with span("dispatch"):
         inputs = [a.array.device_value() for a in e.args]
         if jdev is not None:
@@ -184,19 +186,30 @@ def _run_device_element(e: ComputationalElement, jdev=None):
             # resident).
             inputs = [jax.device_put(x, jdev) for x in inputs]
         result = e.fn(*inputs)
-    writable = [a.array for a in e.args if a.mode.writes]
-    with span("block"):
+        outs = ()
         if writable:
             outs = result if isinstance(result, (tuple, list)) else (result,)
             if len(outs) != len(writable):
                 raise ValueError(
                     f"kernel {e.name}: returned {len(outs)} outputs for "
                     f"{len(writable)} writable args")
-            for ma, val in zip(writable, outs):
-                if hasattr(val, "block_until_ready"):
-                    val.block_until_ready()
-                ma.set_physical_device(val)
-        elif result is not None and hasattr(result, "block_until_ready"):
+        # Read-ahead: the copy of an output the host is expected to read
+        # starts as soon as the kernel has finished on the device, so it
+        # overlaps the host's own work until the read.  A host value
+        # (a kernel returning numpy) has nothing to copy.
+        ahead = [pos in e.read_ahead and hasattr(val, "copy_to_host_async")
+                 for (pos, _), val in zip(writable, outs)]
+        for (_, ma), val, early in zip(writable, outs, ahead):
+            if early:
+                with span("d2h_ahead", uid=e.uid, nbytes=ma.nbytes):
+                    val.copy_to_host_async()
+    with span("block"):
+        for (_, ma), val, early in zip(writable, outs, ahead):
+            if hasattr(val, "block_until_ready"):
+                val.block_until_ready()
+            ma.set_physical_device(val, ahead=early)
+        if not writable and result is not None and hasattr(
+                result, "block_until_ready"):
             result.block_until_ready()
 
 

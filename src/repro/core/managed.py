@@ -44,6 +44,13 @@ class ManagedArray:
         # MemoryManager's note_spill/note_reload transitions; part of the
         # capture slot state so replayed plans reload from the right tier.
         self.backing_tier: Optional[str] = None
+        # Read-ahead bookkeeping (GrScheduler's predictor): ``write_site``
+        # is the call site of the kernel output this array holds until the
+        # host or a later kernel first touches it; ``device_ahead`` says the
+        # installed device value's copy to the host was started on its lane
+        # when the kernel was dispatched.
+        self.write_site = None
+        self.device_ahead = False
         self.aid = next(_ARRAY_IDS)
         self.name = name or f"arr{self.aid}"
 
@@ -71,9 +78,11 @@ class ManagedArray:
             return self.device
         return self.host
 
-    def set_physical_device(self, value) -> None:
-        """Called by executors when a kernel/transfer materializes a value."""
+    def set_physical_device(self, value, ahead: bool = False) -> None:
+        """Called by executors when a kernel/transfer materializes a value;
+        ``ahead`` when its copy to the host is already under way."""
         self.device = value
+        self.device_ahead = ahead
 
     # -- host access API (triggers scheduling) --------------------------
     def read(self) -> np.ndarray:
@@ -153,8 +162,8 @@ class ManagedValue:
     def device_value(self):
         return self.device
 
-    def set_physical_device(self, value) -> None:
-        self.device = value
+    def set_physical_device(self, value, ahead: bool = False) -> None:
+        self.device = value     # no host mirror: nothing is copied ahead
 
     def get(self):
         self._scheduler._sync_against(self, writes=False)

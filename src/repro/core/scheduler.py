@@ -41,6 +41,66 @@ from .timeline import Timeline, span
 _PLAN_LAUNCH_OVERHEAD_S = 5e-6
 
 
+class ReadAheadPredictor:
+    """Learns which kernel outputs the host reads back, so that their copy
+    to the host starts on the lane as the kernel is dispatched
+    (``ComputationalElement.read_ahead``) instead of inside the host read.
+
+    One saturating counter (0-3) per site, the call site of a writable
+    argument: declared function, call name (the name the kernel history
+    keys on) and argument position.  It goes up by one when a host access
+    of the site's output needs a D2H, and down by one when a later kernel
+    consumes or overwrites the output before any host access.  An output is
+    marked when its site's counter is at 2 or more, so a read-ahead needs
+    two host reads with no device consumer in between.  Only
+    ``ManagedArray`` outputs (with a host mirror) are marked.  Called under
+    the submission pipeline's lock.
+    """
+
+    TOP = 3
+    ENGAGE = 2
+
+    def __init__(self) -> None:
+        self.counters: dict = {}
+        self.issued = 0     # outputs marked for an early copy
+        self.hits = 0       # D2Hs of a value whose copy started early
+
+    def _step(self, site, delta: int) -> None:
+        self.counters[site] = min(self.TOP, max(
+            0, self.counters.get(site, 0) + delta))
+
+    def on_launch(self, e: ComputationalElement) -> None:
+        """Decay the sites of unread outputs ``e`` consumes or overwrites,
+        then record ``e``'s outputs and mark the ones the host should
+        read."""
+        for a in e.args:
+            site = getattr(a.array, "write_site", None)
+            if site is not None:
+                self._step(site, -1)
+                a.array.write_site = None
+        marks = []
+        for pos, a in enumerate(e.args):
+            if a.mode.writes and isinstance(a.array, ManagedArray):
+                site = a.array.write_site = (e.fn_key, e.name, pos)
+                if self.counters.get(site, 0) >= self.ENGAGE:
+                    marks.append(pos)
+        if marks:
+            e.read_ahead = tuple(marks)
+            self.issued += len(marks)
+
+    def on_host_access(self, ma: ManagedArray, d2h: bool) -> None:
+        """The host touched ``ma``; ``d2h`` when that needed its device
+        copy."""
+        if ma.write_site is not None:
+            if d2h:
+                self._step(ma.write_site, +1)
+            ma.write_site = None
+
+    def stats(self) -> dict:
+        return {"d2h_ahead_issued": self.issued,
+                "d2h_ahead_hits": self.hits}
+
+
 class GrScheduler:
     def __init__(self,
                  policy: str = "parallel",
@@ -117,6 +177,9 @@ class GrScheduler:
         # everything submitted from ``position`` on.  Cleared with
         # ``_elements`` at every full sync; cheap enough to keep always-on.
         self._host_log: List[tuple] = []
+        # Which kernel outputs the host reads back: their copy to the host
+        # starts on the lane, as the kernel is dispatched.
+        self.read_ahead = ReadAheadPredictor()
         # Sanitizer runtime mode (repro.analysis): version-vector race
         # detection at element boundaries.  Off by default — with
         # ``sanitize=False`` no hook is installed and scheduling is
@@ -234,6 +297,7 @@ class GrScheduler:
             # the pipeline runs, so auto-inserted transfer children inherit
             # the same EDF rank.
             self.deadlines.tag(e)
+            self.read_ahead.on_launch(e)
             if self.policy == "parallel":
                 self.pipeline.run(e)
             else:
@@ -334,7 +398,9 @@ class GrScheduler:
             with self.pipeline:
                 if self.dag.has_device_frontier(dep_key(ma), writes):
                     continue    # a racing launch re-dirtied the array
-                if ma.device_valid and not ma.host_valid:
+                d2h = ma.device_valid and not ma.host_valid
+                self.read_ahead.on_host_access(ma, d2h)
+                if d2h:
                     self._d2h(ma)
                 elif getattr(ma, "backing_tier", None) is not None:
                     self._tier_restore(ma)
@@ -365,8 +431,12 @@ class GrScheduler:
             ex.timeline.record(-1, f"d2h_{ma.name}", "d2h", None, t0, ex.host_time)
         else:
             t0 = ex.host_now()
-            with span("d2h", nbytes=ma.nbytes):
+            # A read-ahead copy started on the lane: np.asarray returns the
+            # fetched host value, or waits only for what is left of it.
+            ahead = ma.device_ahead
+            with span("d2h", nbytes=ma.nbytes, ahead=int(ahead)):
                 ma.host = np.asarray(ma.device)
+            self.read_ahead.hits += ahead
             ex.timeline.record(-1, f"d2h_{ma.name}", "d2h", None, t0, ex.host_now())
         ma.host_valid = True
 
@@ -501,6 +571,7 @@ class GrScheduler:
                     "edges": self.dag.num_edges,
                     "d2d_transfers": self.d2d_transfers,
                     **self.pipeline.stats(),
+                    **self.read_ahead.stats(),
                     **self.streams.stats(),
                     **self.executor.history.stats(),
                     **self.plan_cache.stats(),

@@ -4,7 +4,8 @@ A small Black-Scholes program (3 stocks x 2 iterations: one upload, one
 kernel and one host read per stock and iteration) runs on the real executor
 under a profiler trace.  Its ``gr:`` spans must mirror the in-memory
 ``Timeline`` element for element, by ``uid``; the spans of one element must
-nest as the code nests; lane work must sit on the lane threads.  With no
+nest as the code nests; lane work must sit on the lane threads, the early
+copies of the outputs the host reads among it.  With no
 trace running, nothing changes but the absence of the spans.
 """
 import sys
@@ -156,6 +157,28 @@ def test_host_wait_and_d2h_nest_in_the_host_read(traced):
         assert any(inside(w, r) for r in reads + syncs), w
     assert any(inside(w, r) for w in named(events, "host_wait")
                for r in reads)
+
+
+def test_read_ahead_copies_start_on_the_lane_inside_the_dispatch(traced):
+    """The warm-up taught the read-ahead predictor that every price is read:
+    each kernel starts its output's copy to the host as it is dispatched."""
+    _, _, events = traced
+    kernels = {e[4]["uid"]: e for e in named(events, "kernel")}
+    ahead = named(events, "d2h_ahead")
+    assert len(ahead) == STOCKS * ITERS
+    launch_lines = {e[3] for e in named(events, "launch", "host_read")}
+    for a in ahead:
+        assert a[4]["nbytes"] == N * 4
+        assert inside(a, kernels[a[4]["uid"]])
+        assert any(inside(a, d) for d in named(events, "dispatch"))
+        assert a[3] not in launch_lines
+
+
+def test_a_read_served_by_an_early_copy_says_so(traced):
+    _, _, events = traced
+    d2h = named(events, "d2h")
+    assert len(d2h) == STOCKS * ITERS
+    assert all(e[4]["ahead"] == 1 for e in d2h)
 
 
 def test_lane_spans_sit_on_other_threads_than_launches(traced):
