@@ -21,6 +21,7 @@ ARCH_IDS: List[str] = [
     "rwkv6_1_6b",
     "internvl2_76b",
     "hymba_1_5b",
+    "jamba2_3b",
 ]
 
 _ALIASES = {i.replace("_", "-"): i for i in ARCH_IDS}

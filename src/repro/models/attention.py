@@ -1,7 +1,8 @@
 """Grouped-query attention: full/sliding-window causal, cross, and cached
-decode.  With ``use_flash`` the blocked-softmax path runs the Pallas flash
-kernel (kernels/flash_attention), which compiles for the TPU only; without
-it, a pure-jnp blocked softmax.
+decode, with RoPE or (``cfg.rope`` False, jamba) no positional encoding.
+With ``use_flash`` the blocked-softmax path runs the Pallas flash kernel
+(kernels/flash_attention), which compiles for the TPU only; without it, a
+pure-jnp blocked softmax.
 """
 from __future__ import annotations
 
@@ -268,9 +269,10 @@ def attend_full(cfg, p, x, positions, *, window: int = 0,
     Returns (out, (k, v)) so prefill can seed the decode cache.
     """
     q, k, v = _project_qkv(cfg, p, x, x)
-    cos, sin = rope_angles(positions, cfg.hd, cfg.rope_theta)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    if cfg.rope:
+        cos, sin = rope_angles(positions, cfg.hd, cfg.rope_theta)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
     S = x.shape[1]
     if use_flash and not bidirectional:
         from ..kernels.flash_attention import ops as flash_ops
@@ -309,10 +311,11 @@ def attend_decode(cfg, p, x, cache, pos, *, window: int = 0):
     """
     B = x.shape[0]
     q, k_new, v_new = _project_qkv(cfg, p, x, x)
-    posv = jnp.full((B, 1), pos, jnp.int32)
-    cos, sin = rope_angles(posv, cfg.hd, cfg.rope_theta)
-    q = apply_rope(q, cos, sin)
-    k_new = apply_rope(k_new, cos, sin)
+    if cfg.rope:
+        posv = jnp.full((B, 1), pos, jnp.int32)
+        cos, sin = rope_angles(posv, cfg.hd, cfg.rope_theta)
+        q = apply_rope(q, cos, sin)
+        k_new = apply_rope(k_new, cos, sin)
     Smax = k_new.shape[1] and cache["k"].shape[1]
     ring = window > 0 and Smax <= window     # ring buffer (slot = pos % W)
     slot = pos % Smax if ring else pos

@@ -32,6 +32,13 @@ class SSMConfig:
     state_dim: int = 16              # mamba state per channel (hymba ssm_state)
     expand: int = 2                  # mamba inner expansion
     conv_dim: int = 4                # mamba depthwise conv width
+    # Mamba-1 as published (Jamba): a low-rank step size (x_proj -> dt_rank,
+    # then dt_proj dt_rank -> inner), RMSNorm of the dt/B/C activations and
+    # a bias on the conv.  The defaults are Hymba's mixer: an elementwise
+    # per-channel step size, no inner norms, no conv bias.
+    dt_rank: int = 0
+    inner_norms: bool = False
+    conv_bias: bool = False
 
 
 @dataclass(frozen=True)
@@ -53,6 +60,11 @@ class ArchConfig:
     # one global layer every `global_every` layers (0 = all global).
     sliding_window: int = 0
     global_every: int = 0
+    # attention/Mamba pattern (jamba): layer i is attention iff
+    # i % attn_period == attn_offset, every other layer a Mamba block.
+    attn_period: int = 0
+    attn_offset: int = 0
+    rope: bool = True                # False: no positional encoding (jamba)
     logit_softcap: float = 0.0
     tie_embeddings: bool = False
     moe: Optional[MoEConfig] = None
@@ -87,6 +99,8 @@ class ArchConfig:
     def layer_group(self) -> int:
         """Layers per scan group (local/global patterns repeat every
         `global_every`; uniform stacks scan layer-by-layer)."""
+        if self.attn_period:
+            return self.attn_period
         return self.global_every if self.global_every > 1 else 1
 
     # -- parameter counting (for roofline MODEL_FLOPS) --------------------
@@ -109,10 +123,22 @@ class ArchConfig:
         if self.family == "ssm":                       # rwkv6 time+channel mix
             attn = 5 * d * d + d * d // 2              # r,k,v,g,o + lora/decay
             ff_all = ff_act = 2 * d * self.d_ff
+        ffn = ff_act if active_only else ff_all
+        if self.family == "hybrid" and self.attn_period:
+            s = self.ssm
+            inner, R, N = s.expand * d, s.dt_rank, s.state_dim
+            mamba = (2 * d * inner + inner * (R + 2 * N) + R * inner + inner
+                     + inner * N + inner + (s.conv_dim + 1) * inner
+                     + inner * d)
+            n_attn = self.n_layers // self.attn_period
+            total = (n_attn * (attn + ffn)
+                     + (self.n_layers - n_attn) * (mamba + ffn))
+            return int(total + self.vocab * d
+                       * (1 if self.tie_embeddings else 2))
         if self.family == "hybrid" and self.ssm:
             inner = self.ssm.expand * d
             attn += 2 * d * inner + inner * (2 * self.ssm.state_dim + 1)
-        per_layer = attn + (ff_act if active_only else ff_all)
+        per_layer = attn + ffn
         total = self.n_layers * per_layer
         total += self.n_encoder_layers * (attn + gates * d * ff)
         total += self.vocab * d * (1 if self.tie_embeddings else 2)
@@ -142,7 +168,8 @@ class ArchConfig:
                 capacity_factor=float(min(8, self.moe.n_experts)))
         if self.ssm:
             changes["ssm"] = dataclasses.replace(
-                self.ssm, head_dim=16, state_dim=4)
+                self.ssm, head_dim=16, state_dim=4,
+                dt_rank=8 if self.ssm.dt_rank else 0)
         return dataclasses.replace(self, **changes)
 
 

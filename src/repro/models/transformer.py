@@ -1,10 +1,12 @@
-"""Unified LM stack for all 10 assigned architectures.
+"""Unified LM stack for all 11 registered architectures.
 
 Layers are scanned in *groups* (``cfg.layer_group``): uniform stacks scan
 layer-by-layer; gemma3's 5-local:1-global pattern scans groups of six with
-static per-position window flags.  Parameters and caches carry a leading
-``n_groups`` axis so the whole stack lowers to one rolled ``lax.scan`` —
-essential to keep the 96-layer/340B HLO small enough to compile.
+static per-position window flags, and jamba's attention/Mamba pattern
+groups of fourteen (one attention block, thirteen Mamba blocks).
+Parameters and caches carry a leading ``n_groups`` axis so the whole stack
+lowers to one rolled ``lax.scan`` — essential to keep the 96-layer/340B
+HLO small enough to compile.
 
 Three entry points per model:
 * ``forward_train``  — full-sequence logits (+ MoE aux losses);
@@ -34,6 +36,9 @@ def layer_kinds(cfg: ArchConfig) -> List[str]:
     if cfg.family == "ssm":
         return ["rwkv"]
     if cfg.family == "hybrid":
+        if cfg.attn_period:
+            return ["attn_global" if i == cfg.attn_offset else "mamba"
+                    for i in range(cfg.attn_period)]
         return ["hymba"]
     g = cfg.layer_group
     if g > 1:  # local:global pattern (gemma3: 5 local then 1 global)
@@ -59,8 +64,9 @@ def init_block(key, cfg: ArchConfig, kind: str, dtype=jnp.float32,
         p["ln2"] = init_norm(cfg, cfg.d_model, dtype)
         p["cmix"] = R.init_channel_mix(ks[1], cfg, dtype)
         return p
-    p["attn"] = A.init_attention(ks[0], cfg, dtype)
-    if kind == "hymba":
+    if kind != "mamba":
+        p["attn"] = A.init_attention(ks[0], cfg, dtype)
+    if kind in ("mamba", "hymba"):
         p["mamba"] = M.init_mamba(ks[1], cfg, dtype)
     if cross:
         p["ln_cross"] = init_norm(cfg, cfg.d_model, dtype)
@@ -79,6 +85,8 @@ def init_block_cache(cfg: ArchConfig, kind: str, batch: int, max_len: int,
         st = R.init_rwkv_state(cfg, batch)
         st["cmix_shift"] = jnp.zeros((batch, cfg.d_model), jnp.float32)
         return st
+    if kind == "mamba":
+        return {"mamba": M.init_mamba_state(cfg, batch)}
     # sliding-window layers only ever attend to the last `window` keys:
     # their cache is a ring buffer of that size (a 32k gemma3/hymba cache
     # would otherwise be ~40x larger than needed)
@@ -123,14 +131,22 @@ def apply_block_seq(cfg, kind, p, x, positions, aux, *, cache=None,
         st["cmix_shift"] = cshift.astype(jnp.float32)
         return x + y2, aux, st
 
+    if kind in ("mamba", "hymba"):
+        with jax.named_scope("mamba"):
+            ym, mstate = M.apply_mamba_seq(
+                cfg, p["mamba"], h,
+                cache["mamba"] if cache is not None
+                else M.init_mamba_state(cfg, x.shape[0]))
+    if kind == "mamba":
+        x, aux = _ffn_part(cfg, p, x + ym, aux)
+        return x, aux, (None if cache is None else {"mamba": mstate})
+
     window = cfg.sliding_window if kind == "attn_local" or kind == "hymba" else 0
-    y, (k, v) = A.attend_full(cfg, p["attn"], h, positions, window=window,
-                              use_flash=use_flash, bidirectional=bidirectional)
+    with jax.named_scope("attn"):
+        y, (k, v) = A.attend_full(cfg, p["attn"], h, positions, window=window,
+                                  use_flash=use_flash,
+                                  bidirectional=bidirectional)
     if kind == "hymba":
-        ym, mstate = M.apply_mamba_seq(
-            cfg, p["mamba"], h,
-            cache["mamba"] if cache is not None
-            else M.init_mamba_state(cfg, x.shape[0]))
         y = 0.5 * (y + ym)
     x = x + y
     if "cross" in p and enc_out is not None:
@@ -174,12 +190,20 @@ def apply_block_decode(cfg, kind, p, x, cache, pos, aux):
         st["cmix_shift"] = cshift.astype(jnp.float32)
         return x + y2, aux, st
 
+    if kind in ("mamba", "hymba"):
+        with jax.named_scope("mamba"):
+            ym, mstate = M.apply_mamba_step(cfg, p["mamba"], h,
+                                            cache["mamba"])
+    if kind == "mamba":
+        x, aux = _ffn_part(cfg, p, x + ym, aux)
+        return x, aux, {"mamba": mstate}
+
     window = cfg.sliding_window if kind in ("attn_local", "hymba") else 0
-    y, kv = A.attend_decode(cfg, p["attn"], h, cache, pos, window=window)
+    with jax.named_scope("attn"):
+        y, kv = A.attend_decode(cfg, p["attn"], h, cache, pos, window=window)
     new_cache = dict(cache)
     new_cache.update(kv)
     if kind == "hymba":
-        ym, mstate = M.apply_mamba_step(cfg, p["mamba"], h, cache["mamba"])
         y = 0.5 * (y + ym)
         new_cache["mamba"] = mstate
     x = x + y

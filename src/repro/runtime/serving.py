@@ -21,6 +21,12 @@ instead of issuing fragments, flushing them once the oldest member ages out
 (or its deadline draws near).  ``submit`` and ``flush`` are thread-safe via
 the scheduler's submission pipeline lock.
 
+Each batch kernel opens a ``gr:prefill`` span (meta ``batch``, ``tokens``)
+around its prefill call and a ``gr:decode`` span (meta ``pos``) around each
+decode step; ``stats()["serving"]`` counts, per (prompt_len, new_tokens)
+shape, the prompt tokens prefilled, the decode steps issued and the bytes
+of the KV cache and of the SSM state that one batch's cache holds.
+
 Per-slot ragged positions (token-level continuous batching) would need a
 vector-``pos`` decode mask; noted as future work in DESIGN.md.
 """
@@ -39,6 +45,7 @@ from ..core import (DEFAULT_TENANT, GrScheduler, make_scheduler,
                     priority_weight)
 from ..core.frontend import GrFunction, function
 from ..core.managed import ManagedValue
+from ..core.timeline import span
 from ..models import init_cache
 from ..models.config import ArchConfig
 from .steps import make_decode_step, make_prefill_step
@@ -61,6 +68,20 @@ class Request:
         if self.deadline_s is None:
             return float("inf")
         return self.t_submit + self.deadline_s
+
+
+def cache_bytes(cfg: ArchConfig, batch: int, max_len: int) -> dict:
+    """Bytes of one batch's cache pytree (``init_cache``): the attention
+    layers' keys and values, and every other layer's recurrent state (SSM
+    state and conv window, RWKV state)."""
+    shapes = jax.eval_shape(lambda: init_cache(cfg, batch, max_len))
+    out = {"kv_cache_bytes": 0, "ssm_state_bytes": 0}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(shapes)[0]:
+        name = getattr(path[-1], "key", None)
+        kind = ("kv_cache_bytes" if name in ("k", "v", "cross_k", "cross_v")
+                else "ssm_state_bytes")
+        out[kind] += int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+    return out
 
 
 class ServingEngine:
@@ -93,6 +114,7 @@ class ServingEngine:
         # function's identity, so a stable GrFunction per shape is what lets
         # steady-state batches replay one plan instead of re-recording.
         self._fns: Dict[tuple, GrFunction] = {}
+        self._shape_stats: Dict[tuple, dict] = {}
 
     # ------------------------------------------------------------------
     def submit(self, tokens: np.ndarray, new_tokens: int = 0, *,
@@ -127,12 +149,15 @@ class ServingEngine:
 
         def kernel(params, toks, _out):
             cache = init_cache(cfg, toks.shape[0], max_len)
-            logits, cache = prefill(params, {"tokens": toks}, cache)
+            with span("prefill", batch=toks.shape[0],
+                      tokens=toks.shape[0] * prompt_len):
+                logits, cache = prefill(params, {"tokens": toks}, cache)
             nxt = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
             outs = [nxt]
             for i in range(new_tokens - 1):
-                nxt, _, cache = decode(params, nxt, cache,
-                                       jnp.int32(prompt_len + i))
+                with span("decode", pos=prompt_len + i):
+                    nxt, _, cache = decode(params, nxt, cache,
+                                           jnp.int32(prompt_len + i))
                 outs.append(nxt)
             return jnp.concatenate(outs, axis=1)
 
@@ -245,6 +270,7 @@ class ServingEngine:
             # launch, i.e. the window restarts at issue time.
             opts["deadline_s"] = min(dls)
         gf = self._batch_fn(plen, ntok).with_options(**opts)
+        self._count_batch(plen, ntok)
         ctx = (self.sched.capture(gf.name) if self.capture
                else contextlib.nullcontext())
         with ctx:
@@ -263,8 +289,21 @@ class ServingEngine:
         self._pending.clear()
         return done
 
+    def _count_batch(self, plen: int, ntok: int) -> None:
+        st = self._shape_stats.get((plen, ntok))
+        if st is None:
+            st = self._shape_stats[(plen, ntok)] = dict(
+                prefill_tokens=0, decode_steps=0,
+                **cache_bytes(self.cfg, self.batch, plen + ntok))
+        st["prefill_tokens"] += self.batch * plen
+        st["decode_steps"] += ntok - 1
+
     def stats(self) -> dict:
-        return self.sched.stats()
+        """The scheduler's counters, and under ``serving`` the engine's per
+        (prompt_len, new_tokens) shape."""
+        with self.sched.pipeline:
+            shapes = {k: dict(v) for k, v in self._shape_stats.items()}
+        return {**self.sched.stats(), "serving": shapes}
 
     def tenant_stats(self) -> dict:
         """Per-tenant QoS (makespan, queueing delay, latency p50/p99)."""

@@ -209,3 +209,82 @@ def test_span_never_imports_jax(monkeypatch):
         sched.sync()
         assert [s.kind for s in sched.timeline.spans] == ["h2d", "compute"]
     assert "jax.profiler" not in sys.modules
+
+
+# ----------------------------------------------------------------------
+# The serving engine's batch kernel: prefill and decode spans, counters
+PROMPT, NEW, BATCH = 12, 5, 2
+
+
+def tiny_hybrid():
+    """One Mamba block and one attention block: both kinds of state."""
+    import dataclasses
+
+    from repro.configs import get_config
+    return dataclasses.replace(get_config("jamba2_3b", reduced=True),
+                               n_layers=2, attn_period=2, attn_offset=1)
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    """One warm-up batch, then one traced batch of the serving engine."""
+    import jax
+
+    from repro.models import init_lm
+    from repro.runtime.serving import ServingEngine
+
+    cfg = tiny_hybrid()
+    params = init_lm(jax.random.PRNGKey(0), cfg)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    log_dir = tmp_path_factory.mktemp("serve_trace")
+    rng = np.random.default_rng(0)
+    with ServingEngine(cfg, params, batch_size=BATCH,
+                       max_new_tokens=NEW) as eng:
+        for traced in (False, True):
+            for _ in range(BATCH):
+                eng.submit(rng.integers(0, cfg.vocab, PROMPT,
+                                        dtype=np.int32))
+            if traced:
+                jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+            try:
+                eng.flush(force=True)
+                eng.collect()
+            finally:
+                if traced:
+                    jax.profiler.stop_trace()
+        stats = eng.stats()
+    return cfg, stats, read_spans(log_dir)
+
+
+def test_a_served_batch_prefills_once_and_decodes_per_token_in_its_kernel(
+        served):
+    _, _, events = served
+    (kernel,) = named(events, "kernel")
+    (prefill,) = named(events, "prefill")
+    decodes = named(events, "decode")
+    assert inside(prefill, kernel)
+    assert prefill[4]["batch"] == BATCH
+    assert prefill[4]["tokens"] == BATCH * PROMPT
+    assert len(decodes) == NEW - 1
+    assert all(inside(d, kernel) and d[1] >= prefill[2] for d in decodes)
+    assert [d[4]["pos"] for d in decodes] == list(range(PROMPT,
+                                                        PROMPT + NEW - 1))
+
+
+def test_serving_counters_match_the_cache_pytree(served):
+    import jax
+
+    from repro.models import init_cache
+    cfg, stats, _ = served
+    st = stats["serving"][(PROMPT, NEW)]
+    assert st["prefill_tokens"] == 2 * BATCH * PROMPT        # two batches
+    assert st["decode_steps"] == 2 * (NEW - 1)
+    cache = init_cache(cfg, BATCH, PROMPT + NEW)
+    kv = sum(a.nbytes for blk in cache for k, a in blk.items()
+             if k in ("k", "v"))
+    ssm = sum(a.nbytes for blk in cache if "mamba" in blk
+              for a in jax.tree_util.tree_leaves(blk["mamba"]))
+    assert kv > 0 and ssm > 0
+    assert (st["kv_cache_bytes"], st["ssm_state_bytes"]) == (kv, ssm)
