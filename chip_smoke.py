@@ -11,7 +11,7 @@ serial and the parallel policy, and checks each against the program's
 its published widths (bfloat16 weights from a seed) through a
 ``ServingEngine`` and through a ``serve_lm`` job of an in-process daemon,
 and checks both bit for bit against a plain loop of the same jitted steps.
-Phase C runs the three Pallas kernels once each at model widths against
+Phase C runs the four Pallas kernels once each at model widths against
 their references.  With ``--chips 4``, B&S and IMG run on four devices under
 the affinity and round-robin placements, against the reference and against
 one device.
@@ -264,6 +264,8 @@ def phase_c():
     from repro.kernels.rmsnorm.ref import rmsnorm_ref
     from repro.kernels.rwkv6.ops import wkv6
     from repro.kernels.rwkv6.ref import wkv6_ref
+    from repro.kernels.selective_scan.ops import selective_scan
+    from repro.kernels.selective_scan.ref import selective_scan_ref
 
     t_phase = time.perf_counter()
     tol = dict(rtol=2e-2, atol=2e-2)         # bfloat16, as test_kernels.py
@@ -310,6 +312,22 @@ def phase_c():
     assert_close(f32(sT).reshape(B * H, hd, hd), f32(sT_ref), "wkv6 state",
                  tol)
     done.append(f"wkv6 {r.shape}")
+
+    # Hymba-1.5B's Mamba prefill scan: inner 3200, state 16, 2048 tokens.
+    B, S, inner, N = 2, 2048, 3200, 16
+    ks = jax.random.split(jax.random.PRNGKey(1), 6)
+    dt = jax.nn.softplus(jax.random.normal(ks[0], (B, S, inner)) - 3.0)
+    u = jax.random.normal(ks[1], (B, S, inner))
+    Bm, Cm = (jax.random.normal(ks[i], (B, S, N)) for i in (2, 3))
+    A_T = -jnp.exp(jax.random.normal(ks[4], (N, inner)) * 0.5)
+    h0 = jax.random.normal(ks[5], (B, N, inner))
+    args = tuple(a.astype(jnp.bfloat16) for a in (dt, u, Bm, Cm)) + (A_T, h0)
+    y, hT = run_compiled_kernel(selective_scan, args, {})
+    y_ref, hT_ref = selective_scan_ref(*args)
+    assert_close(f32(y), f32(y_ref), "selective_scan y", tol)
+    assert_close(f32(hT), f32(hT_ref), "selective_scan state",
+                 dict(rtol=1e-4, atol=1e-4))
+    done.append(f"selective_scan {dt.shape}")
 
     print(f"phase C PASS: {'; '.join(done)}; each compiled with "
           f"tpu_custom_call and matched its ref.py; {smoke_time(t_phase)}",

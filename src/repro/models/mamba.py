@@ -11,25 +11,29 @@ published: one ``x_proj`` to (dt_rank + 2N), RMSNorm of the dt, B and C
 activations (``inner_norms``), then ``dt_proj`` dt_rank -> inner with a
 bias, and a bias on the depthwise conv (``conv_bias``).
 
-Sequence mode scans time in chunks, and forms each step's decay and drive
-inside the chunk's body from that step's Δ, u and B, so no
-(batch, seq, inner, state) array exists at any length; the chunk bodies are
-checkpointed, so a backward pass keeps one state per chunk.  The steps run
-one after another, a few to a loop iteration: on a TPU v5e, at 4 x 8192
-tokens and Jamba's widths, that took 17.8 ms a layer where an associative
-scan over each chunk's (batch, chunk, state, inner) decay and drive took
-331 ms.  Decode mode is
-an O(1) state update.  The state is laid out (batch, state, inner), inner
+Sequence mode forms each step's decay and drive from that step's Δ, u and
+B, so no (batch, seq, inner, state) array exists at any length.  On a TPU,
+outside a sharding mesh and at a width of whole 128-lane tiles
+(``scan_kernel_engaged``), the recurrence is the Pallas kernel
+``kernels/selective_scan``: the state stays in VMEM, Δ, u, B and C are read
+in place and y is written once.  Elsewhere it is an XLA scan over time in
+checkpointed chunks, a few steps to a loop iteration, which keeps one state
+per chunk for a backward pass.  On a TPU v5e, at 4 x 8192 tokens and
+Jamba's widths, the XLA scan took 17.8-20.9 ms a layer and the kernel 8.0
+ms.  The kernel's backward pass is that XLA scan's.  Decode mode is an
+O(1) state update.  The state is laid out (batch, state, inner), inner
 minor, as the TPU tiles it.
 """
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ..sharding.context import use_weight
+from ..kernels.selective_scan import ops as scan_ops
+from ..sharding.context import mesh_active, use_weight
 from .layers import normal_init, rms_norm_heads
 
 SCAN_CHUNK = 128         # time steps per checkpointed chunk of the scan
@@ -149,8 +153,43 @@ def selective_scan(dt, u, Bm, Cm, A_T, h0):
     return y, h_last
 
 
+def _xla_scan(dt, u, Bm, Cm, A_T, h0):
+    """``selective_scan`` on the model's activations: float32 inside, y
+    back in u.dtype."""
+    f32 = jnp.float32
+    y, h_last = selective_scan(dt.astype(f32), u.astype(f32), Bm.astype(f32),
+                               Cm.astype(f32), A_T, h0)
+    return y.astype(u.dtype), h_last
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _kernel_scan(dt, u, Bm, Cm, A_T, h0, interpret):
+    """The Pallas scan forward; its gradient is ``_xla_scan``'s."""
+    return scan_ops.selective_scan(dt, u, Bm, Cm, A_T, h0,
+                                   interpret=interpret)
+
+
+def _kernel_scan_fwd(dt, u, Bm, Cm, A_T, h0, interpret):
+    return (_kernel_scan(dt, u, Bm, Cm, A_T, h0, interpret),
+            (dt, u, Bm, Cm, A_T, h0))
+
+
+def _kernel_scan_bwd(interpret, res, g):
+    return jax.vjp(_xla_scan, *res)[1](g)
+
+
+_kernel_scan.defvjp(_kernel_scan_fwd, _kernel_scan_bwd)
+
+
+def scan_kernel_engaged(cfg) -> bool:
+    """Whether ``apply_mamba_seq`` runs the Pallas scan: on a TPU, outside
+    a sharding mesh, and at an inner width of whole 128-lane tiles."""
+    return (jax.default_backend() == "tpu" and not mesh_active()
+            and (cfg.ssm.expand * cfg.d_model) % 128 == 0)
+
+
 def apply_mamba_seq(cfg, p, x, state) -> Tuple[jnp.ndarray, dict]:
-    """x: (B, S, d). Chunked sequential scan over time in fp32."""
+    """x: (B, S, d). Sequential scan over time in fp32."""
     B, S, d = x.shape
     s = cfg.ssm
     inner = s.expand * d
@@ -159,10 +198,12 @@ def apply_mamba_seq(cfg, p, x, state) -> Tuple[jnp.ndarray, dict]:
     u, conv_state = _causal_conv_seq(p, u, state["conv"])
     dt, Bm, Cm = _features(cfg, p, u)
     A_T = -jnp.exp(p["A_log"].astype(jnp.float32)).T                # (N,inner)
-    f32 = jnp.float32
-    y, h_last = selective_scan(dt.astype(f32), u.astype(f32), Bm.astype(f32),
-                               Cm.astype(f32), A_T, state["h"])
-    y = y.astype(x.dtype) + u * p["D"].astype(x.dtype)[None, None]
+    if scan_kernel_engaged(cfg):
+        y, h_last = _kernel_scan(dt, u, Bm, Cm, A_T, state["h"],
+                                 jax.default_backend() != "tpu")
+    else:
+        y, h_last = _xla_scan(dt, u, Bm, Cm, A_T, state["h"])
+    y = y + u * p["D"].astype(x.dtype)[None, None]
     y = y * jax.nn.silu(z)
     out = y @ use_weight(p["w_out"].astype(x.dtype), ("model", None))
     return out, {"h": h_last, "conv": conv_state.astype(jnp.float32)}

@@ -24,8 +24,10 @@ the scheduler's submission pipeline lock.
 Each batch kernel opens a ``gr:prefill`` span (meta ``batch``, ``tokens``)
 around its prefill call and a ``gr:decode`` span (meta ``pos``) around each
 decode step; ``stats()["serving"]`` counts, per (prompt_len, new_tokens)
-shape, the prompt tokens prefilled, the decode steps issued and the bytes
-of the KV cache and of the SSM state that one batch's cache holds.
+shape, the prompt tokens prefilled, those of them whose Mamba layers ran
+the Pallas selective scan (``scan_kernel_tokens``), the decode steps issued
+and the bytes of the KV cache and of the SSM state that one batch's cache
+holds.
 
 Per-slot ragged positions (token-level continuous batching) would need a
 vector-``pos`` decode mask; noted as future work in DESIGN.md.
@@ -46,7 +48,7 @@ from ..core import (DEFAULT_TENANT, GrScheduler, make_scheduler,
 from ..core.frontend import GrFunction, function
 from ..core.managed import ManagedValue
 from ..core.timeline import span
-from ..models import init_cache
+from ..models import init_cache, layer_kinds, mamba
 from ..models.config import ArchConfig
 from .steps import make_decode_step, make_prefill_step
 
@@ -115,6 +117,9 @@ class ServingEngine:
         # steady-state batches replay one plan instead of re-recording.
         self._fns: Dict[tuple, GrFunction] = {}
         self._shape_stats: Dict[tuple, dict] = {}
+        self._scan_kernel = (
+            any(k in ("mamba", "hymba") for k in layer_kinds(cfg))
+            and mamba.scan_kernel_engaged(cfg))
 
     # ------------------------------------------------------------------
     def submit(self, tokens: np.ndarray, new_tokens: int = 0, *,
@@ -293,9 +298,11 @@ class ServingEngine:
         st = self._shape_stats.get((plen, ntok))
         if st is None:
             st = self._shape_stats[(plen, ntok)] = dict(
-                prefill_tokens=0, decode_steps=0,
+                prefill_tokens=0, scan_kernel_tokens=0, decode_steps=0,
                 **cache_bytes(self.cfg, self.batch, plen + ntok))
         st["prefill_tokens"] += self.batch * plen
+        if self._scan_kernel:
+            st["scan_kernel_tokens"] += self.batch * plen
         st["decode_steps"] += ntok - 1
 
     def stats(self) -> dict:

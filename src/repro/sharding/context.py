@@ -32,6 +32,11 @@ def _mesh():
     return getattr(_STATE, "mesh", None)
 
 
+def mesh_active() -> bool:
+    """Whether use-site constraints are active: inside ``sharding_rules``."""
+    return _mesh_axes() is not None
+
+
 def _named(spec: P):
     return NamedSharding(_mesh(), spec)
 

@@ -25,6 +25,7 @@ from repro.benchsuite import kernels as K
 from repro.kernels.flash_attention.kernel import flash_attention_bhsd
 from repro.kernels.rmsnorm.kernel import rmsnorm_2d
 from repro.kernels.rwkv6.kernel import wkv6_bh
+from repro.kernels.selective_scan.ops import selective_scan
 
 
 @pytest.fixture(scope="module")
@@ -81,6 +82,20 @@ def test_wkv6_compiles_at_rwkv6_widths(one_chip):
     u = _spec((BH, hd), jnp.bfloat16, one_chip)
     s0 = _spec((BH, hd, hd), jnp.float32, one_chip)
     compiled = wkv6_bh.lower(seq, seq, seq, seq, u, s0).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("inner,S", [(5120, 8192), (3200, 2048), (3200, 37)])
+def test_selective_scan_compiles_at_model_widths(one_chip, inner, S):
+    # The Mamba prefill scan of Jamba2-3B (inner 5120) and Hymba-1.5B
+    # (3200), state 16, a batch of 4 prompts, bf16 activations; a short
+    # prompt is padded to one chunk of 128 steps.
+    B, N = 4, 16
+    seq = _spec((B, S, inner), jnp.bfloat16, one_chip)
+    bc = _spec((B, S, N), jnp.bfloat16, one_chip)
+    compiled = selective_scan.lower(
+        seq, seq, bc, bc, _spec((N, inner), jnp.float32, one_chip),
+        _spec((B, N, inner), jnp.float32, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
