@@ -9,10 +9,12 @@ giving up the paper's core premise (no upfront program structure):
 * ``with scheduler.capture(name):`` — a transparent recording context.  The
   first episode under a given ``name``/signature runs eagerly while its
   launches are traced into an immutable :class:`ExecutionPlan`; later
-  episodes are matched launch-by-launch against the cached plan and replayed
-  through a fast path that skips ``ComputationDAG.add``,
-  ``StreamManager.place``/``assign`` and the per-element launch overhead
-  (one reduced plan-launch overhead is charged instead).
+  episodes are matched launch-by-launch against the cached plan and replayed.
+  A replayed element enters the runtime through the submission pipeline's
+  admission stage, like an eager one, but with the plan's parents and lanes
+  (``ComputationDAG.adopt``, ``StreamManager.bind_to_lane``) in place of
+  dependency inference, placement and lane assignment, and with one reduced
+  plan-launch overhead per episode in place of one overhead per element.
 * ``scheduler.replay(plan, bindings)`` — explicit re-submission of a whole
   plan with fresh arrays bound by slot.
 
@@ -39,6 +41,7 @@ from typing import (Any, Callable, Dict, List, Mapping, Optional, Sequence,
 
 from .element import (AccessMode, Arg, ComputationalElement, DEFAULT_TENANT,
                       ElementKind, dep_key)
+from .submission import Placement
 
 _PLAN_IDS = itertools.count()
 
@@ -480,8 +483,9 @@ class _Recorder:
             self.record(ce)
 
     def record(self, e: ComputationalElement) -> None:
-        """Trace one scheduled element (called from ``GrScheduler._schedule``
-        after DAG insertion, before the scheduler flips location bits)."""
+        """Trace one scheduled element (called from the submission
+        pipeline's admission stage after DAG insertion, before the element's
+        location bits flip)."""
         arg_slots = tuple((self._slot_for(a.array), a.mode) for a in e.args)
         parents = tuple(self._idx_of_uid[p.uid] for p in e.parents
                         if p.uid in self._idx_of_uid)
@@ -539,8 +543,7 @@ class _ReplayState:
         self.kpos = 0                          # next kernel to match
         self.written: set = set()              # slots written in-session
         self.started = False
-        self.lanes = sched.streams.reserve(plan.key, plan.lane_devices,
-                                           sched.executor.is_done)
+        self.lanes = sched._plan_lanes(plan)
         # The plan's captured default arrays (persistent weights etc.) are
         # pinned against replay-time eviction even before the episode binds
         # them: evicting one would flip its location bits and guarantee a
@@ -604,63 +607,41 @@ def _match_kernel(plan: ExecutionPlan, kpos: int, bound: List[Any],
     return new_bind
 
 
-def _apply_location_bits(sched, pe: PlanElement, bound: List[Any]) -> None:
-    """Logical data-location updates at schedule time — the same
-    MemoryManager transitions the eager pipeline performs, so a replayed
-    (or capture-demoted) episode keeps location bits and resident-set
-    accounting in lockstep with the eager path."""
-    mem = sched.memory
-    if pe.kind is ElementKind.TRANSFER:
-        mem.note_h2d(bound[pe.arg_slots[0][0]], pe.device)
-    elif pe.kind is ElementKind.D2D:
-        mem.note_d2d(bound[pe.arg_slots[0][0]], pe.device)
-    elif pe.kind is ElementKind.EVICT:
-        # Plan-carried evictions are *scheduled* (part of the captured —
-        # possibly Belady-rewritten — memory schedule), not reactive.
-        cfg = dict(pe.config)
-        tier = mem.tier_named(cfg["tier"]) if cfg.get("tier") else None
-        if tier is not None:
-            mem.note_spill(bound[pe.arg_slots[0][0]], tier,
-                           cfg.get("spill_target"), pe.transfer_bytes,
-                           scheduled=True)
-        else:
-            mem.note_evict(bound[pe.arg_slots[0][0]], scheduled=True)
-    elif pe.kind is ElementKind.RELOAD:
-        mem.note_reload(bound[pe.arg_slots[0][0]], pe.device)
-    else:
-        for slot, mode in pe.arg_slots:
-            if mode.writes:
-                mem.note_device_write(bound[slot], pe.device)
-
-
 def _flush_range(sched, r: _ReplayState, hi_inclusive: int,
                  kernel_fn: Optional[Callable] = None,
                  use_plan_fns: bool = False) -> ComputationalElement:
-    """Materialize and batch-submit plan elements ``r.flushed .. hi``.
-
-    Fresh ``ComputationalElement``s are created with slot-bound arrays and
-    pre-resolved parents; the DAG adopts them without inference, the
-    pre-reserved lanes receive them without assignment, and the executor
-    gets one batch with pre-materialized event lists.  Only the *first* use
-    of each slot consults the live frontier (entry dependencies) so that
-    replays chain correctly behind earlier eager/replayed work touching the
-    same arrays."""
+    """Replay plan elements ``r.flushed .. hi``.  The plan's own part is
+    done here: the episode's one plan-launch overhead, the budget reserve,
+    and fresh elements bound to the episode's arrays by slot, with their
+    plan parents, lanes and wait events and the args of slots at first use
+    (ordered behind the live frontier, so a replay chains behind earlier
+    work on the same arrays).  The submission pipeline's admission stage
+    does the rest, as for eager elements, and submits them at once."""
     plan = r.plan
-    bounded = sched.memory.bounded
     if not r.started:
         # The whole episode costs one reduced plan-launch overhead
         # (cudaGraphLaunch analogue) instead of one overhead per element.
         sched.executor.host_overhead(sched.plan_launch_overhead_s)
         r.started = True
-        if bounded and plan.mem_scheduled:
+        if sched.memory.bounded and plan.mem_scheduled:
             # The plan carries its own Belady evict/reload schedule: make
             # room for its recorded per-device peak once, up front (stale
             # foreign leftovers are the only possible victims), then let
             # the plan's own EVICT elements manage its working set.
             sched.pipeline.reserve_plan(
                 plan, extra_pinned=r.pinned.union(r.bound_keys))
-    is_done = sched.executor.is_done
-    items = []
+    sched.pipeline.schedule(_plan_items(sched, r, hi_inclusive, kernel_fn,
+                                        use_plan_fns))
+    r.flushed = hi_inclusive + 1
+    return r.new_elements[hi_inclusive]
+
+
+def _plan_items(sched, r: _ReplayState, hi_inclusive: int,
+                kernel_fn: Optional[Callable], use_plan_fns: bool):
+    """Yield ``(element, Placement)`` for plan elements ``r.flushed .. hi``,
+    lazily: an element's reserve runs once those before it are admitted."""
+    plan = r.plan
+    reserve = sched.memory.bounded and not plan.mem_scheduled
     for idx in range(r.flushed, hi_inclusive + 1):
         pe = plan.elements[idx]
         if pe.kind is ElementKind.KERNEL:
@@ -674,10 +655,8 @@ def _flush_range(sched, r: _ReplayState, hi_inclusive: int,
             transfer_bytes=pe.transfer_bytes,
             priority=pe.priority, tenant=pe.tenant,
             deadline_s=pe.deadline_s, fn_key=pe.fn_key)
-        # Re-stamp the absolute deadline at *replay* submission time (the
-        # capture-time deadline_t would be long expired) and register with
-        # the monitor for EDF/risk tracking.
-        sched.deadlines.tag(ce)
+        # No deadline_t: the absolute deadline is stamped at *replay*
+        # submission time (the capture-time one would be long expired).
         ce.device = pe.device
         ce.src_device = pe.src_device
         ce.device_pinned = pe.pinned    # survives a seed_from_replay re-trace
@@ -688,8 +667,7 @@ def _flush_range(sched, r: _ReplayState, hi_inclusive: int,
             tname = plan.configs[idx].get("tier")
             if tname:
                 ce.tier = sched.memory.tier_named(tname)
-        if bounded and not plan.mem_scheduled \
-                and pe.kind is not ElementKind.EVICT:
+        if reserve and pe.kind is not ElementKind.EVICT:
             # Replays reserve dynamically too: plan gating guarantees the
             # plan's *own* peak fits the budget, but stale foreign arrays
             # (earlier episodes' leftovers) may still hold bytes — evict
@@ -700,39 +678,14 @@ def _flush_range(sched, r: _ReplayState, hi_inclusive: int,
             # schedule, so the reactive reserve must not interleave.)
             sched.pipeline.reserve(
                 ce, extra_pinned=r.pinned.union(r.bound_keys))
-        parents = [r.new_elements[p] for p in pe.parents]
-        seen = {p.uid for p in parents}
-        entry: List[ComputationalElement] = []
-        for s, m in pe.arg_slots:
-            if s in r.written:
-                continue    # session already owns this slot's frontier
-            for d in sched.dag.live_deps(dep_key(r.bound[s]), writes=m.writes):
-                if d.uid not in seen and d is not ce and not d.is_host:
-                    seen.add(d.uid)
-                    entry.append(d)
-        ce.parents = parents + entry
-        sched.dag.adopt(ce)
-        for s, m in pe.arg_slots:
-            if m.writes:
-                r.written.add(s)
-        lane = r.lanes[pe.lane]
-        sched.streams.bind_to_lane(lane, ce)
-        events = [r.new_elements[w] for w in pe.wait_events
-                  if not is_done(r.new_elements[w])]
-        events += [d for d in entry if not is_done(d)]
-        items.append((ce, lane.lane_id, events))
+        ce.parents = [r.new_elements[p] for p in pe.parents]
+        # A slot the session has written already owns its frontier.
+        entry = tuple(a for a, (s, _) in zip(ce.args, pe.arg_slots)
+                      if s not in r.written)
+        r.written.update(s for s, m in pe.arg_slots if m.writes)
+        waits = [r.new_elements[w] for w in pe.wait_events]
         r.new_elements.append(ce)
-        sched._elements.append(ce)
-        if pe.kind is ElementKind.D2D:
-            sched.d2d_transfers += 1
-        _apply_location_bits(sched, pe, r.bound)
-    sched.executor.submit_batch(items)
-    if items and items[-1][0].deadline_t is not None:
-        # Deadline'd replay flush: run the submission-time risk check once
-        # per batch (the caller holds the pipeline lock on this path).
-        sched.deadlines.on_submit(items[-1][0])
-    r.flushed = hi_inclusive + 1
-    return r.new_elements[hi_inclusive]
+        yield ce, Placement(r.lanes[pe.lane], waits, entry)
 
 
 def replay_plan(sched, plan: ExecutionPlan,
@@ -827,10 +780,10 @@ class CaptureContext:
     Modes:
 
     * ``match``  — a cached plan for ``name`` exists; user launches are
-      matched positionally against its kernels and submitted through the
-      replay fast path (intervening transfer/D2D plan elements ride along);
-    * ``record`` — no (matching) plan; the episode runs eagerly while
-      ``_schedule`` traces it, and a plan is stored on clean exit;
+      matched positionally against its kernels and replayed (intervening
+      transfer/D2D plan elements ride along);
+    * ``record`` — no (matching) plan; the episode runs eagerly while the
+      admission stage traces it, and a plan is stored on clean exit;
     * ``eager``  — divergence was detected (plan invalidated) or the
       scheduler policy is serial; pure passthrough.
     """
@@ -900,14 +853,8 @@ class CaptureContext:
                     violations = verify_plan(plan)
                     if violations:
                         raise PlanVerificationError(plan.name, violations)
-                for displaced in self.sched.plan_cache.store(plan):
-                    self.sched.streams.unreserve(displaced.key)
+                self.sched._store_plan(plan)
         return False
-
-    def _drop(self, plan: ExecutionPlan) -> None:
-        """Invalidate a diverged plan and free its reserved lane sets."""
-        self.sched.plan_cache.invalidate(plan)
-        self.sched.streams.unreserve(plan.key)
 
     def _diverge(self, r: _ReplayState) -> None:
         """Mid-episode divergence: the already-replayed prefix matched (and
@@ -916,7 +863,7 @@ class CaptureContext:
         cached without waiting for another full eager episode.  (Distinct
         episode shapes are still best given distinct capture names —
         alternating shapes under one name re-record every switch.)"""
-        self._drop(r.plan)
+        self.sched._drop_plan(r.plan)
         self.recorder = _Recorder()
         self.recorder.seed_from_replay(r)
         self.replay = None

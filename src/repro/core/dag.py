@@ -25,9 +25,9 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 from types import MappingProxyType
-from typing import Dict, List, Mapping, Optional, Set, Tuple
+from typing import Dict, List, Mapping, Optional, Sequence, Set, Tuple
 
-from .element import AccessMode, ComputationalElement
+from .element import AccessMode, Arg, ComputationalElement
 
 # Prune a state's reader list once it grows past this many entries (a
 # long-lived const array — e.g. serving weights — otherwise accumulates
@@ -49,9 +49,9 @@ class DAGSnapshot:
 
     ``writers``/``readers`` map argument keys to the *live* (active) elements
     that could still introduce a dependency on that array; retired elements
-    are excluded.  A debugging/introspection surface (the replay fast path
-    itself uses the targeted :meth:`ComputationDAG.live_deps`) — mutating
-    the returned mappings raises."""
+    are excluded.  A debugging/introspection surface (a replay's entry
+    dependencies use the targeted :meth:`ComputationDAG.live_deps`) —
+    mutating the returned mappings raises."""
 
     writers: Mapping[int, ComputationalElement]
     readers: Mapping[int, Tuple[ComputationalElement, ...]]
@@ -146,18 +146,30 @@ class ComputationDAG:
         self._maybe_retire(element)
 
     # ------------------------------------------------------------------
-    def adopt(self, element: ComputationalElement) -> None:
-        """Fast-path insert for a replayed element with **pre-resolved**
-        parents (``element.parents`` set by the caller from an
-        :class:`~repro.core.capture.ExecutionPlan`).
+    def adopt(self, element: ComputationalElement,
+              entry: Sequence[Arg] = ()) -> List[ComputationalElement]:
+        """Insert a replayed element whose plan parents are pre-resolved
+        (``element.parents``); the admission stage of the submission
+        pipeline calls this for every replayed element.
 
-        Per-array frontier state is transitioned exactly as :meth:`add` would
-        (writes consume the previous frontier's dependency-set entries, reads
-        join the reader list) but the O(frontier) parent inference is
-        skipped — that is the capture/replay fast path."""
+        ``entry`` are the args of slots the episode has not written yet:
+        their live frontier (earlier eager or replayed work on the same
+        arrays) joins the parents and is returned.  Per-array frontier state
+        is transitioned exactly as :meth:`add` would, but the O(frontier)
+        inference of the plan's own edges is skipped."""
+        seen = {p.uid for p in element.parents}
+        found: List[ComputationalElement] = []
+        for a in entry:
+            for d in self.live_deps(a.key, writes=a.mode.writes):
+                if d.uid not in seen and not d.is_host:
+                    seen.add(d.uid)
+                    found.append(d)
+        if found:
+            element.parents = list(element.parents) + found
         for key, mode in element.arg_modes():
             self._transition(key, mode, element)
         self._install(element)
+        return found
 
     def live_deps(self, key: int, writes: bool) -> List[ComputationalElement]:
         """Elements the host (or a replayed episode) must order against

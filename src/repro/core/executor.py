@@ -73,18 +73,12 @@ class Executor:
         clock mid-advance, the host clock on real executors."""
         return self.host_now()
 
-    def submit(self, element: ComputationalElement, lane_id: int,
-               wait_parents: List[ComputationalElement]) -> None:
+    def submit(self, items) -> None:
+        """Submit ``(element, lane_id, wait_parents)`` triples in
+        topological order: one eager element, or one flush of a replayed
+        plan.  In the runtime, the submission pipeline's admission stage
+        is the only caller."""
         raise NotImplementedError
-
-    def submit_batch(self, items) -> None:
-        """Submit a pre-scheduled batch (capture/replay fast path).
-
-        ``items`` is a sequence of ``(element, lane_id, wait_parents)``
-        triples in topological order.  Subclasses override to pre-materialize
-        completion events / start the whole batch at once."""
-        for element, lane_id, waits in items:
-            self.submit(element, lane_id, waits)
 
     def is_done(self, element: ComputationalElement) -> bool:
         raise NotImplementedError
@@ -330,18 +324,10 @@ class ThreadLaneExecutor(Executor):
             worker = self._lanes[lane_id] = _LaneWorker(lane_id, self)
         return worker
 
-    def submit(self, element, lane_id, wait_parents) -> None:
-        element.done_event = threading.Event()
-        element.error = None
-        element.state = ElementState.QUEUED
-        element.t_issue = self.host_now()
-        self._submitted.append(element)
-        self._worker(lane_id).q.put((element, list(wait_parents)))
-
-    def submit_batch(self, items) -> None:
+    def submit(self, items) -> None:
         # Pre-materialize every completion event before anything is
         # enqueued: a worker may dequeue a child and wait on a sibling-lane
-        # parent that has not been individually submitted yet.
+        # parent that has not been enqueued yet.
         for element, _, _ in items:
             element.done_event = threading.Event()
             element.error = None
@@ -478,13 +464,8 @@ class SimExecutor(Executor):
         self._advance_to(self.host_time)
 
     # -- submission ------------------------------------------------------
-    def submit(self, element, lane_id, wait_parents) -> None:
-        self._enqueue(element, lane_id)
-        self._try_start()
-
-    def submit_batch(self, items) -> None:
-        # Replay fast path: enqueue the whole pre-scheduled episode, then
-        # run a single readiness scan instead of one per element.
+    def submit(self, items) -> None:
+        # Enqueue the whole list, then run a single readiness scan.
         for element, lane_id, _ in items:
             self._enqueue(element, lane_id)
         self._try_start()

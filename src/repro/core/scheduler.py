@@ -210,13 +210,6 @@ class GrScheduler:
         e.state = ElementState.DONE
         e.t_start = e.t_end = self.executor.host_now()
 
-    def _schedule(self, e: ComputationalElement) -> None:
-        """DAG insert + lane assignment + submission (parallel policy).
-
-        Thin alias kept for backward compatibility; the staged path lives in
-        :class:`~repro.core.submission.SubmissionPipeline`."""
-        self.pipeline.schedule(e)
-
     # ------------------------------------------------------------------
     def launch(self, fn: Optional[Callable], args: Sequence[Arg], *,
                name: str = "", cost_s: float = 0.0,
@@ -284,7 +277,7 @@ class GrScheduler:
                                      device=device, fn_key=fn_key,
                                      deadline_s=deadline_s)
                 if replayed is not None:
-                    return replayed     # plan hit: submitted via the fast path
+                    return replayed     # plan hit: replayed by the capture
             e = ComputationalElement(fn=fn, args=tuple(args),
                                      kind=ElementKind.KERNEL, name=name,
                                      config=config, cost_s=cost_s,
@@ -297,7 +290,6 @@ class GrScheduler:
             # the pipeline runs, so auto-inserted transfer children inherit
             # the same EDF rank.
             self.deadlines.tag(e)
-            self.read_ahead.on_launch(e)
             if self.policy == "parallel":
                 self.pipeline.run(e)
             else:
@@ -306,14 +298,7 @@ class GrScheduler:
                 if self.auto_prefetch:
                     self.pipeline.prefetch(e.args, priority=priority,
                                            tenant=tenant)
-                self.pipeline.serial(e)
-            # Logical location update at schedule time: the kernel's writable
-            # outputs will live on device; host copies become stale.  Routed
-            # through the MemoryManager so residency tracks the bits.
-            dev = e.device if e.device is not None else 0
-            for a in e.args:
-                if a.mode.writes:
-                    self.memory.note_device_write(a.array, dev)
+                self.pipeline.schedule([(e, None)])
             return e
 
     def _tune(self, name: str, tune: dict) -> dict:
@@ -471,8 +456,9 @@ class GrScheduler:
 
         The first episode under ``name`` (per structural signature) runs
         eagerly and is traced into an :class:`ExecutionPlan`; later episodes
-        that issue the identical launch sequence are replayed through the
-        fast path, skipping DAG inference, lane assignment and per-element
+        that issue the identical launch sequence are replayed: their
+        elements pass the same admission stage with the plan's parents and
+        lanes, skipping DAG inference, lane assignment and per-element
         launch overhead.  Divergence invalidates the plan and the episode
         continues eagerly — capture never changes program semantics.  Under
         the serial policy the context is a no-op passthrough."""
@@ -507,11 +493,24 @@ class GrScheduler:
         with self.pipeline:
             new = _optimize(self, plan)
             if new is not plan:
-                self.plan_cache.invalidate(plan)
-                self.streams.unreserve(plan.key)
-                for displaced in self.plan_cache.store(new):
-                    self.streams.unreserve(displaced.key)
+                self._drop_plan(plan)
+                self._store_plan(new)
             return new
+
+    def _plan_lanes(self, plan: ExecutionPlan) -> dict:
+        """A lane set reserved for one replay of ``plan``."""
+        return self.streams.reserve(plan.key, plan.lane_devices,
+                                    self.executor.is_done)
+
+    def _store_plan(self, plan: ExecutionPlan) -> None:
+        """Cache ``plan``, freeing the lane sets of the plans it displaces."""
+        for displaced in self.plan_cache.store(plan):
+            self.streams.unreserve(displaced.key)
+
+    def _drop_plan(self, plan: ExecutionPlan) -> None:
+        """Invalidate ``plan`` and free its reserved lane sets."""
+        self.plan_cache.invalidate(plan)
+        self.streams.unreserve(plan.key)
 
     # ------------------------------------------------------------------
     def sync(self) -> None:

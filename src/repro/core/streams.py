@@ -2,8 +2,8 @@
 
 CUDA streams map to GrJAX *lanes*: ordered dispatch queues that serialize the
 elements assigned to them while different lanes proceed independently.  On a
-real TPU deployment a lane is a per-device async dispatch queue or a submesh
-(see DESIGN.md §2); the assignment algorithm below is the paper's, verbatim:
+real TPU deployment a lane is a per-device async dispatch queue or a
+submesh; the assignment algorithm below is the paper's, verbatim:
 
 * lanes are reused in FIFO order; a new lane is created **only** when no
   currently-empty lane exists;
@@ -520,8 +520,9 @@ class StreamManager:
                     lane.reserved = False
 
     def bind_to_lane(self, lane: Lane, element: ComputationalElement) -> None:
-        """Replay fast path: place ``element`` on a pre-reserved lane,
-        skipping placement and the assignment algorithm entirely."""
+        """Place a replayed ``element`` on its pre-reserved plan lane,
+        skipping placement and the assignment algorithm entirely (called
+        by the submission pipeline's admission stage)."""
         element.stream = lane.lane_id
         element.device = lane.device_id
         lane.add(element)

@@ -1,15 +1,11 @@
 """SubmissionPipeline — the explicit, lock-protected submission path.
 
-Historically the stages of issuing one computational element (device
-placement, argument prefetch, cross-device migration, DAG insertion, lane
-assignment, executor submission) were inlined across ``GrScheduler.launch``
-and ``GrScheduler._schedule``; correct only when a single host thread talked
-to the scheduler.  Multi-tenant serving has *concurrent* submitters, so the
-pipeline is now an explicit object with one re-entrant lock guarding every
-stage:
+Every computational element enters the runtime through this object.
+Multi-tenant serving has *concurrent* submitters, so one re-entrant lock
+guards every stage:
 
-    place -> reserve (EVICT) -> prefetch (H2D) -> migrate (D2D) -> DAG-add
-          -> lane-assign -> submit
+    place -> reserve (EVICT) -> reload -> prefetch (H2D) -> migrate (D2D)
+          -> schedule (DAG, lane, executor)
 
 The lock is held across the whole pipeline for one element (plus the host
 synchronization paths), which keeps the paper's dependency inference sound
@@ -18,14 +14,16 @@ executor's clocks are only ever mutated by the lock holder.  Submissions from
 different threads serialize at the pipeline; the *executors* still overlap
 device work freely (that is the whole point of lanes).
 
-The pipeline is deliberately a thin, orderable object: each stage is a
-method, so subclasses (or tests) can instrument/override individual stages
-without re-implementing ``launch``.
+The last stage, :meth:`SubmissionPipeline.schedule`, admits every element,
+eager or replayed from a captured plan (capture.py), into the DAG, a lane
+and the executor.
 """
 from __future__ import annotations
 
+import itertools
 import threading
-from typing import TYPE_CHECKING, Iterable, Optional, Sequence, Set
+from typing import (TYPE_CHECKING, Iterable, List, NamedTuple, Optional,
+                    Sequence, Set, Tuple)
 
 from .element import (Arg, ComputationalElement, DEFAULT_TENANT, ElementKind,
                       inout)
@@ -33,6 +31,16 @@ from .timeline import span
 
 if TYPE_CHECKING:  # pragma: no cover
     from .scheduler import GrScheduler
+    from .streams import Lane
+
+
+class Placement(NamedTuple):
+    """A replayed element's place, resolved by its plan: its lane, its wait
+    events, and the args whose live frontier it also orders behind."""
+
+    lane: "Lane"
+    waits: List[ComputationalElement]
+    entry: Tuple[Arg, ...]
 
 
 class SubmissionPipeline:
@@ -101,7 +109,7 @@ class SubmissionPipeline:
                 self.migrate(e.args, e.device, priority=e.priority,
                              tenant=e.tenant, deadline_s=e.deadline_s,
                              deadline_t=e.deadline_t)
-        self.schedule(e)
+        self.schedule([(e, None)])
 
     def reserve(self, e: ComputationalElement,
                 extra_pinned: Optional[Iterable[int]] = None) -> None:
@@ -112,16 +120,18 @@ class SubmissionPipeline:
         one EVICT element — an async D2H write-back (clean copies just
         drop) ordered *after* the victim's last reader by the ordinary
         dependency rules, exactly like the paper's transparent H2D/D2D
-        insertion.  Evictions inherit the triggering element's priority and
-        tenant: making room is work done on that element's behalf.
+        insertion.  Evictions inherit the triggering element's priority,
+        tenant and deadline (stamped here first): making room is work done
+        on that element's behalf.
 
-        ``extra_pinned`` forwards to :meth:`MemoryManager.reserve` — the
-        replay fast path pins its plan-bound arrays so only foreign
-        leftovers are evicted under a replay."""
+        ``extra_pinned`` forwards to :meth:`MemoryManager.reserve` — a
+        replay pins its plan-bound arrays so only foreign leftovers are
+        evicted under a replay."""
         sched = self.sched
         mem = sched.memory
         if not mem.bounded:
             return
+        sched.deadlines.tag(e)
         for ma in mem.reserve(e.device, e, sched.dag.has_device_frontier,
                               extra_pinned):
             self.evict(ma, priority=e.priority, tenant=e.tenant,
@@ -165,46 +175,24 @@ class SubmissionPipeline:
         host-tier spill stays on the D2H engine but the tier stores/encodes
         the payload instead of the plain host write-back.  A stack-wide
         miss — or no stack at all — is the flat PR 5 D2H spill."""
-        sched = self.sched
         dirty = not getattr(ma, "host_valid", True)
-        tier = plan = None
-        if dirty:
-            tier, plan = sched.memory.select_tier(ma)
-        if tier is None:
-            t = ComputationalElement(
-                fn=None, args=(inout(ma),), kind=ElementKind.EVICT,
-                name=f"evict_{ma.name}",
-                transfer_bytes=ma.nbytes if dirty else 0,
-                config={"writeback": dirty}, priority=priority, tenant=tenant,
-                deadline_s=deadline_s, deadline_t=deadline_t)
-            t.device = ma.device_id if ma.device_id is not None else 0
-            if sched.policy == "parallel":
-                self.schedule(t)
-            else:
-                self.serial(t)
-            sched.memory.note_evict(ma)
-            return t
-        src = ma.device_id if ma.device_id is not None else 0
-        target = plan.get("target")
-        wire = int(plan.get("transfer_bytes", ma.nbytes))
+        tier, plan = (self.sched.memory.select_tier(ma) if dirty
+                      else (None, None))
+        plan = plan or {}
         t = ComputationalElement(
             fn=None, args=(inout(ma),), kind=ElementKind.EVICT,
-            name=f"evict_{ma.name}", transfer_bytes=wire,
-            config=dict({"writeback": True}, **plan.get("config", {})),
+            name=f"evict_{ma.name}",
+            transfer_bytes=(int(plan.get("transfer_bytes", ma.nbytes))
+                            if dirty else 0),
+            config=dict({"writeback": dirty}, **plan.get("config", {})),
             priority=priority, tenant=tenant,
             deadline_s=deadline_s, deadline_t=deadline_t)
         t.tier = tier
-        if tier.location == "device":
-            t.device = target       # runs on the (src -> target) D2D link
-            t.src_device = src
-            sched.d2d_transfers += 1
-        else:
-            t.device = src          # runs on the source's D2H engine
-        if sched.policy == "parallel":
-            self.schedule(t)
-        else:
-            self.serial(t)
-        sched.memory.note_spill(ma, tier, target, wire)
+        t.device = ma.device_id if ma.device_id is not None else 0
+        if tier is not None and tier.location == "device":
+            # Runs on the (source -> target) D2D link.
+            t.device, t.src_device = plan.get("target"), t.device
+        self.schedule([(t, None)])
         return t
 
     def reload(self, args: Sequence[Arg], device: int, *,
@@ -238,11 +226,7 @@ class SubmissionPipeline:
                 deadline_s=deadline_s, deadline_t=deadline_t)
             t.tier = tier
             t.device = device
-            if sched.policy == "parallel":
-                self.schedule(t)
-            else:
-                self.serial(t)
-            sched.memory.note_reload(ma, device)
+            self.schedule([(t, None)])
 
     def prefetch(self, args: Sequence[Arg], device: int = 0, *,
                  priority: int = 0, tenant: str = DEFAULT_TENANT,
@@ -253,7 +237,6 @@ class SubmissionPipeline:
         The transfers inherit the consuming kernel's priority/tenant (and
         deadline): a latency-critical kernel's input upload must not be
         accounted (or de-prioritized) as someone else's work."""
-        sched = self.sched
         for a in args:
             ma = a.array
             if a.mode.reads and ma.host_valid and not ma.device_valid:
@@ -263,13 +246,7 @@ class SubmissionPipeline:
                     priority=priority, tenant=tenant,
                     deadline_s=deadline_s, deadline_t=deadline_t)
                 t.device = device
-                if sched.policy == "parallel":
-                    self.schedule(t)
-                else:
-                    self.serial(t)
-                # Logical location update at schedule time (see managed.py),
-                # via the MemoryManager so residency tracks the bits.
-                sched.memory.note_h2d(ma, device)
+                self.schedule([(t, None)])
 
     def migrate(self, args: Sequence[Arg], device: int, *,
                 priority: int = 0, tenant: str = DEFAULT_TENANT,
@@ -278,14 +255,14 @@ class SubmissionPipeline:
         """Move device-resident read args owned by *other* devices onto
         ``device`` via D2D transfer elements (single-copy ownership model:
         the copy migrates, it is not replicated)."""
-        sched = self.sched
         for a in args:
             ma = a.array
             if not a.mode.reads or not getattr(ma, "device_valid", False):
                 continue
             src = getattr(ma, "device_id", None)
             if src is None:
-                sched.memory.note_d2d(ma, device)  # claim unowned copies
+                # Claim an unowned copy: no element, no bytes moved.
+                self.sched.memory.note_d2d(ma, device)
                 continue
             if src == device:
                 continue
@@ -296,44 +273,90 @@ class SubmissionPipeline:
                 deadline_s=deadline_s, deadline_t=deadline_t)
             t.device = device
             t.src_device = src
-            self.schedule(t)
-            sched.memory.note_d2d(ma, device)
-            sched.d2d_transfers += 1
+            self.schedule([(t, None)])
 
-    def schedule(self, e: ComputationalElement) -> None:
-        """DAG insert + lane assignment + submission (parallel policy)."""
-        sched = self.sched
-        with span("schedule", uid=e.uid) as sp:
-            # Idempotent deadline stamp: kernels arrive tagged from
-            # _launch, auto children carry inherited deadline_t; both still
-            # register with the monitor here (direct schedule() callers get
-            # stamped).
-            sched.deadlines.tag(e)
-            sched.executor.host_overhead(sched.launch_overhead_s)
-            if sched.sanitizer is not None:
-                sched.sanitizer.on_schedule(e)
-            sched.dag.add(e)
-            lane, events = sched.streams.assign(e, sched.executor.is_done)
-            sp.set_metadata(lane=lane.lane_id)
-            sched.executor.submit(e, lane.lane_id, events)
-            sched._elements.append(e)
-            self.submissions += 1
-            # Submission-time deadline-risk check (may preempt queued bulk
-            # work).
-            sched.deadlines.on_submit(e)
-            if sched._capture is not None:
-                sched._capture.trace(e)
+    def schedule(self, items: Iterable[
+            Tuple[ComputationalElement, Optional[Placement]]]) -> None:
+        """Admission stage: the one way an element enters the DAG, a lane
+        and the executor.
 
-    def serial(self, e: ComputationalElement) -> None:
-        """Original GrCUDA behaviour: blocking, in-order, single lane, no
-        dependency computation (overheads even smaller, §V-C)."""
+        Each item is ``(element, placement)``.  An eager element (None)
+        costs one launch overhead and gets inferred parents (``dag.add``)
+        and a lane (``streams.assign``); a replayed one brings its plan
+        parents on ``element.parents`` and a :class:`Placement`
+        (``dag.adopt``, ``streams.bind_to_lane``); under the serial policy
+        each element runs alone on lane 0.  ``items`` may be lazy: each
+        element is admitted before the next is drawn, so a replay's
+        per-element reserve sees the location bits before it.  Then one
+        executor submission for them all (the serial policy waits for it)
+        and one deadline-risk check, on the last."""
         sched = self.sched
-        sched.executor.host_overhead(sched.launch_overhead_s)
-        e.parents = []
-        sched.executor.submit(e, 0, [])
-        sched.executor.wait(e)
-        sched._elements.append(e)
-        self.submissions += 1
+        ex = sched.executor
+        serial = sched.policy != "parallel"
+        batch = []
+        for e, placed in items:
+            with span("schedule", uid=e.uid) as sp:
+                # Idempotent: kernels arrive tagged from _launch and
+                # transfer children with an inherited deadline_t.
+                sched.deadlines.tag(e)
+                if placed is None:
+                    ex.host_overhead(sched.launch_overhead_s)
+                if sched.sanitizer is not None:
+                    sched.sanitizer.on_schedule(e)
+                if serial:
+                    e.parents, waits = [], []
+                elif placed is None:
+                    sched.dag.add(e)
+                    _, waits = sched.streams.assign(e, ex.is_done)
+                else:
+                    entry = sched.dag.adopt(e, placed.entry)
+                    sched.streams.bind_to_lane(placed.lane, e)
+                    waits = [w for w in itertools.chain(placed.waits, entry)
+                             if not ex.is_done(w)]
+                lane_id = e.stream if e.stream is not None else 0
+                sp.set_metadata(lane=lane_id)
+                if e.kind is ElementKind.KERNEL:
+                    sched.read_ahead.on_launch(e)
+                if sched._capture is not None:
+                    # Before the location bits: a recorded slot keeps the
+                    # state its array had at first use.
+                    sched._capture.trace(e)
+                self._note_location(e, planned=placed is not None)
+                sched._elements.append(e)
+                self.submissions += 1
+                batch.append((e, lane_id, waits))
+        ex.submit(batch)
+        if serial:
+            for e, _, _ in batch:
+                ex.wait(e)
+        sched.deadlines.on_submit(batch[-1][0])
+
+    def _note_location(self, e: ComputationalElement, planned: bool) -> None:
+        """Flip the location bits an element implies, by its kind, through
+        the MemoryManager so residency tracks them.  A ``planned`` EVICT is
+        part of a plan's (maybe Belady) memory schedule, not reactive."""
+        sched = self.sched
+        mem = sched.memory
+        ma = e.args[0].array if e.args else None
+        dev = e.device if e.device is not None else 0
+        if e.src_device is not None:
+            sched.d2d_transfers += 1    # D2D copies and peer-tier spills
+        if e.kind is ElementKind.TRANSFER:
+            mem.note_h2d(ma, dev)
+        elif e.kind is ElementKind.D2D:
+            mem.note_d2d(ma, dev)
+        elif e.kind is ElementKind.RELOAD:
+            mem.note_reload(ma, dev)
+        elif e.kind is ElementKind.EVICT:
+            if e.tier is not None:
+                mem.note_spill(ma, e.tier, e.config.get("spill_target"),
+                               e.transfer_bytes, scheduled=planned)
+            else:
+                mem.note_evict(ma, scheduled=planned)
+        else:
+            for a in e.args:
+                if a.mode.writes:
+                    mem.note_device_write(a.array, dev)
 
     # -- stats -----------------------------------------------------------
     def stats(self) -> dict:

@@ -358,9 +358,9 @@ def test_sim_executor_overlap_raises_through_hooks():
     # Bypass dependency inference: two conflicting writers, no parents, on
     # two lanes — they start at the same sim timestamp and must trip the
     # sanitizer the moment the second one begins.
-    s.executor.submit(e1, 0, ())
+    s.executor.submit([(e1, 0, ())])
     with pytest.raises(SanitizerError, match="WAW"):
-        s.executor.submit(e2, 1, ())
+        s.executor.submit([(e2, 1, ())])
     assert s.stats()["sanitizer_races_detected"] == 1
 
 

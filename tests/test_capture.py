@@ -1,12 +1,15 @@
 """Graph capture & replay: plan recording, cache keying/invalidation,
 replay correctness (real executor) and replay performance (simulator vs the
 CUDA-Graphs oracle of §V-D)."""
+import gc
 import statistics
 
 import numpy as np
 import pytest
 
+from repro import api as gr
 from repro.core import const, inout, make_scheduler, out
+from repro.core.element import dep_key
 
 
 def _episode(s, n=1024, cost=1e-4, tag=""):
@@ -515,3 +518,87 @@ def test_plan_cache_overflow_releases_displaced_reservations():
     leaked = [l.lane_id for l in s.streams.lanes.values()
               if l.reserved and l.lane_id not in reserved_ids]
     assert not leaked
+
+
+# ----------------------------------------------------------------------
+# One admission path: a replay leaves the state an eager episode leaves
+# ----------------------------------------------------------------------
+
+_ADM_SQ = gr.function(lambda x, o: x * x, modes=("const", "out"),
+                     name="adm_sq", cost_s=1e-4)
+_ADM_ADD = gr.function(lambda a, b, o: a + b, modes=("const", "const", "out"),
+                      name="adm_add", cost_s=1e-4)
+
+
+def _four_kernel_episode(s, tag):
+    """Two squares, their sum, and a square of the sum, on fresh arrays:
+    four kernels, so round-robin placement restarts every episode."""
+    sq = _ADM_SQ.with_options(scheduler=s)
+    add = _ADM_ADD.with_options(scheduler=s)
+    x1 = s.array(np.ones(256, np.float32), name=f"x1{tag}")
+    x2 = s.array(np.full(256, 2.0, np.float32), name=f"x2{tag}")
+    y1, y2, z, w = (s.array(shape=(256,), dtype=np.float32,
+                            name=f"{v}{tag}") for v in ("y1", "y2", "z", "w"))
+    sq(x1, y1)
+    sq(x2, y2)
+    add(y1, y2, z)
+    sq(z, w)
+    return [x1, x2, y1, y2, z, w]
+
+
+def _admission_state(s, arrays, submitted_before):
+    """What admitting an episode leaves behind, with elements named by
+    their position among the episode's submissions (a replayed transfer
+    keeps the name it was recorded under)."""
+    pos = {e.uid: i for i, e in enumerate(s._elements)}
+
+    def frontier(a, writes):
+        return sorted(pos[d.uid] for d in s.dag.live_deps(dep_key(a), writes))
+
+    return {"bits": [(a.host_valid, a.device_valid, a.device_id,
+                      a.backing_tier) for a in arrays],
+            "resident": [p.resident_bytes for p in s.memory.pools],
+            "d2d_transfers": s.d2d_transfers,
+            "submitted": (s.stats()["pipeline_submissions"]
+                          - submitted_before),
+            "frontier": [(frontier(a, True), frontier(a, False))
+                         for a in arrays]}
+
+
+@pytest.mark.parametrize("setup", [
+    {},
+    {"num_devices": 2},
+    {"memory_budget": 3 * 4 * 256},
+], ids=["one-device", "two-devices", "budget-spills"])
+def test_replay_leaves_the_state_an_eager_episode_leaves(setup):
+    """Replayed elements pass the same admission stage as eager ones, so
+    each replayed episode leaves the location bits, device ids, resident
+    bytes, D2D count, submission count and DAG frontier of every slot that
+    the same episode run eagerly leaves."""
+    def run(capture):
+        s = make_scheduler("parallel", simulate=True, plan_optimize=False,
+                           **setup)
+        states = []
+        for ep in range(3):
+            before = s.stats()["pipeline_submissions"]
+            if capture:
+                with s.capture("admission"):
+                    arrays = _four_kernel_episode(s, ep)
+            else:
+                arrays = _four_kernel_episode(s, ep)
+            states.append(_admission_state(s, arrays, before))
+            s.sync()
+            del arrays
+            gc.collect()        # the next episode starts with no residents
+        st = s.stats()
+        s.close()
+        return states, st
+
+    eager, _ = run(capture=False)
+    replayed, st = run(capture=True)
+    assert st["plan_records"] == 1 and st["plan_replays"] == 2
+    assert replayed == eager
+    if "num_devices" in setup:
+        assert eager[0]["d2d_transfers"] > 0
+    if "memory_budget" in setup:
+        assert st["mem_spills"] > 0 and st["mem_evicts_scheduled"] > 0

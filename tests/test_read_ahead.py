@@ -70,6 +70,27 @@ def test_outputs_are_bit_identical_to_an_unmarked_copy():
         assert got.tobytes() == want.tobytes()
 
 
+def test_a_replayed_output_the_host_reads_every_time_is_read_ahead():
+    """Under capture, every episode after the first is replayed from its
+    plan; a replayed kernel passes the same admission stage as an eager
+    one, so its output engages and is read ahead the same way."""
+    with make_scheduler("parallel") as sched:
+        marked, seen, got = [], [], []
+        for i in range(6):
+            with sched.capture("bs_step"):
+                e, out = launch_and_read(sched, i)
+            marked.append(e.read_ahead)
+            seen.append(counts(sched))
+            got.append(out)
+        assert sched.stats()["plan_replays"] == 5
+    assert marked == [(), ()] + [(1,)] * 4
+    assert seen == [(0, 0), (0, 0), (1, 1), (2, 2), (3, 3), (4, 4)]
+    for i, out in enumerate(got):
+        with make_scheduler("parallel") as fresh:    # eager, never marked
+            _, want = launch_and_read(fresh, i)
+        assert out.tobytes() == want.tobytes()
+
+
 def test_outputs_consumed_on_the_device_are_never_issued():
     """VEC: SQUARE's outputs feed REDUCE_DIFF; only its 1-element result is
     read, so only that site engages."""
