@@ -284,6 +284,24 @@ def phase_c():
     assert_close(f32(got), f32(ref), "flash_attention", tol)
     done.append(f"flash_attention {q.shape}x{k.shape} window 1024")
 
+    # Jamba2-3B prefill: 20 query heads over one KV head of 128, causal.
+    # The reference materialises its scores, so it runs a batch row and
+    # four heads at a time.
+    B, S, H, Hkv, hd = 4, 8192, 20, 1, 128
+    q = jax.random.normal(ks[0], (B, S, H, hd), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (B, S, Hkv, hd), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (B, S, Hkv, hd), jnp.bfloat16)
+    got = f32(run_compiled_kernel(flash_attention, (q, k, v), {}))
+    with jax.default_matmul_precision("highest"):
+        for b in range(B):
+            for h in range(0, H, 4):
+                ref = attention_ref(q[b:b + 1, :, h:h + 4], k[b:b + 1],
+                                    v[b:b + 1])
+                assert_close(got[b:b + 1, :, h:h + 4], f32(ref),
+                             f"flash_attention batch {b} heads {h}-{h + 3}",
+                             tol)
+    done.append(f"flash_attention {q.shape}x{k.shape} causal")
+
     for d in (1600, 2048):
         x = jax.random.normal(ks[3], (4096, d), jnp.bfloat16)
         scale = jax.random.normal(ks[4], (d,), jnp.bfloat16) * 0.1 + 1.0

@@ -47,7 +47,7 @@ def roofline_terms(stats, mem, chips: int, cfg, cell) -> dict:
 
 
 def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
-             use_flash: bool = False, seq_shard=None,
+             seq_shard=None,
              remat: bool = True, verbose: bool = True) -> dict:
     cfg = get_config(arch)
     cell = SHAPES[shape_name]
@@ -58,8 +58,8 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     mesh = make_production_mesh(multi_pod=multi_pod)
     chips = int(np.prod(list(mesh.shape.values())))
     t0 = time.time()
-    lowering = build_cell(cfg, cell, mesh, use_flash=use_flash,
-                          remat=remat, seq_shard=seq_shard)
+    lowering = build_cell(cfg, cell, mesh, remat=remat,
+                          seq_shard=seq_shard)
     with mesh:
         jitted = jax.jit(lowering.fn,
                          in_shardings=lowering.in_shardings,
@@ -120,7 +120,6 @@ def main() -> None:
     ap.add_argument("--shape", default=None, help="shape cell (default: all)")
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--both-meshes", action="store_true")
-    ap.add_argument("--use-flash", action="store_true")
     ap.add_argument("--seq-shard", action="store_true", default=None)
     ap.add_argument("--no-remat", action="store_true")
     ap.add_argument("--out", default="results/dryrun")
@@ -137,7 +136,6 @@ def main() -> None:
                 tag = f"{arch}__{shape}__{'multi' if mp else 'single'}"
                 try:
                     res = run_cell(arch, shape, multi_pod=mp,
-                                   use_flash=args.use_flash,
                                    seq_shard=args.seq_shard,
                                    remat=not args.no_remat)
                 except Exception as e:

@@ -134,14 +134,14 @@ class CellLowering(NamedTuple):
 
 
 def make_optimizer(cfg: ArchConfig) -> AdamW:
-    # 8-bit optimizer state for the very large dense configs (DESIGN.md §5):
+    # 8-bit optimizer state for the very large dense configs:
     # fp32 moments for <=200B params fit the pod; 340B needs int8 moments.
     quantized = cfg.param_count() > 200e9
     return AdamW(lr=3e-4, quantized=quantized)
 
 
 def build_cell(cfg: ArchConfig, cell: ShapeCell, mesh, *,
-               use_flash: bool = False, remat: bool = True,
+               remat: bool = True,
                seq_shard: Optional[bool] = None) -> CellLowering:
     """Lowerable artifact for one (arch x shape x mesh) cell."""
     optimizer = make_optimizer(cfg)
@@ -162,7 +162,6 @@ def build_cell(cfg: ArchConfig, cell: ShapeCell, mesh, *,
                                  P(*((None,) + tuple(bs[k]))))
                 for k in b_specs}
         step = _with_rules(make_train_step(cfg, optimizer,
-                                           use_flash=use_flash,
                                            remat=remat,
                                            seq_shard=seq_shard), mesh)
         metrics_shapes = jax.eval_shape(step, st, b_specs)[1]
@@ -194,7 +193,7 @@ def build_cell(cfg: ArchConfig, cell: ShapeCell, mesh, *,
             batch["patches"] = jax.ShapeDtypeStruct(
                 (B, cfg.n_frontend_tokens, cfg.d_model), np.float32)
             b_sh["patches"] = NamedSharding(mesh, P(dp, None, None))
-        step = _with_rules(make_prefill_step(cfg, use_flash=use_flash), mesh)
+        step = _with_rules(make_prefill_step(cfg), mesh)
         logits_sh = NamedSharding(mesh, fit_spec((dp, "model"),
                                                  (B, cfg.vocab), mesh))
         return CellLowering(step, (params, batch, cache),

@@ -1,16 +1,26 @@
 """Grouped-query attention: full/sliding-window causal, cross, and cached
 decode, with RoPE or (``cfg.rope`` False, jamba) no positional encoding.
-With ``use_flash`` the blocked-softmax path runs the Pallas flash kernel
-(kernels/flash_attention), which compiles for the TPU only; without it, a
-pure-jnp blocked softmax.
+
+Short sequences take a plain softmax over the whole score matrix.  From
+``CHUNKED_THRESHOLD`` tokens on, causal self-attention is blocked online
+softmax: on a TPU, outside a sharding mesh and at shapes the kernel's
+blocks divide (``flash_kernel_engaged``), the Pallas flash kernel
+``kernels/flash_attention``, whose score blocks never leave VMEM and which
+skips the blocks above the diagonal and outside the window; elsewhere the
+same algorithm in XLA (``_sdpa_chunked``).  Both share one backward pass,
+the chunked path's, which recomputes the score blocks from the saved
+log-sum-exp.
 """
 from __future__ import annotations
 
+import functools
 
 import jax
 import jax.numpy as jnp
 
-from ..sharding.context import pin_attention_blocks, shard_heads, use_weight
+from ..kernels.flash_attention import ops as flash_ops
+from ..sharding.context import (mesh_active, pin_attention_blocks,
+                                shard_heads, use_weight)
 from .layers import apply_rope, normal_init, rms_norm_heads, rope_angles
 
 NEG_INF = -1e30
@@ -70,9 +80,6 @@ def _sdpa(cfg, q, k, v, mask):
     return o.reshape(B, Sq, H * hd)
 
 
-import functools as _functools
-
-
 def _block_scores(qblk, kblk, qi, kj, q_chunk, kv_chunk, *, causal, window,
                   softcap):
     """Masked (softcapped) score block in f32.  qblk pre-scaled.
@@ -95,13 +102,12 @@ def _block_scores(qblk, kblk, qi, kj, q_chunk, kv_chunk, *, causal, window,
     return s, tgrad, mask
 
 
-@_functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _chunked_attention(q, k, v, causal, window, softcap, q_chunk, kv_chunk):
     """Flash attention in pure lax: blocked online softmax with an O(S·d)
     custom VJP that recomputes score blocks (the autodiff'd scan would save
-    every (m, l, acc) carry — ~19 GB/layer at 4k x d18432).  This is both
-    the XLA fallback for long sequences and the numerical reference for the
-    Pallas kernel."""
+    every (m, l, acc) carry — ~19 GB/layer at 4k x d18432).  The XLA path
+    for long sequences wherever the Pallas kernel is not engaged."""
     out, _ = _chunked_fwd_impl(q, k, v, causal, window, softcap, q_chunk,
                                kv_chunk)
     return out
@@ -234,21 +240,66 @@ def _chunked_bwd(causal, window, softcap, q_chunk, kv_chunk, res, dout):
 _chunked_attention.defvjp(_chunked_fwd, _chunked_bwd)
 
 
-def _sdpa_chunked(cfg, q, k, v, *, causal: bool = True, window: int = 0,
-                  q_chunk: int = 512, kv_chunk: int = 1024):
-    B, Sq, H, hd = q.shape
-    Skv = k.shape[1]
+def _chunks(Sq: int, Skv: int, q_chunk: int = 512, kv_chunk: int = 1024):
     q_chunk = min(q_chunk, Sq)
     kv_chunk = min(kv_chunk, Skv)
     while Sq % q_chunk:
         q_chunk //= 2
     while Skv % kv_chunk:
         kv_chunk //= 2
+    return q_chunk, kv_chunk
+
+
+def _sdpa_chunked(cfg, q, k, v, *, causal: bool = True, window: int = 0,
+                  q_chunk: int = 512, kv_chunk: int = 1024):
+    q_chunk, kv_chunk = _chunks(q.shape[1], k.shape[1], q_chunk, kv_chunk)
     return _chunked_attention(q, k, v, causal, window,
                               float(cfg.logit_softcap), q_chunk, kv_chunk)
 
 
+def _flash_call(q, k, v, window, softcap, with_lse):
+    B, Sq, H, hd = q.shape
+    res = flash_ops.flash_attention(
+        q, k, v, window=window, softcap=softcap, with_lse=with_lse,
+        interpret=jax.default_backend() != "tpu")
+    o = res[0] if with_lse else res
+    o = o.reshape(B, Sq, H * hd)
+    return (o, res[1]) if with_lse else o
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _flash_attention(q, k, v, window, softcap):
+    """Causal attention by the Pallas kernel, (B, Sq, H * hd); its
+    gradient is the chunked path's, from the kernel's log-sum-exp."""
+    return _flash_call(q, k, v, window, softcap, with_lse=False)
+
+
+def _flash_fwd(q, k, v, window, softcap):
+    out, lse = _flash_call(q, k, v, window, softcap, with_lse=True)
+    return out, (q, k, v, out, lse)
+
+
+def _flash_bwd(window, softcap, res, dout):
+    q_chunk, kv_chunk = _chunks(res[0].shape[1], res[1].shape[1])
+    return _chunked_bwd(True, window, softcap, q_chunk, kv_chunk, res, dout)
+
+
+_flash_attention.defvjp(_flash_fwd, _flash_bwd)
+
+
 CHUNKED_THRESHOLD = 2048
+
+
+def flash_kernel_engaged(cfg, S: int, *, bidirectional: bool = False
+                         ) -> bool:
+    """Whether ``attend_full`` runs the Pallas flash kernel over ``S``
+    tokens: on a TPU, outside a sharding mesh, for causal attention from
+    ``CHUNKED_THRESHOLD`` tokens on, at a length the kernel's blocks
+    divide."""
+    return (jax.default_backend() == "tpu" and not mesh_active()
+            and not bidirectional and S >= CHUNKED_THRESHOLD
+            and flash_ops.block_sizes(S, S, cfg.n_heads // cfg.n_kv_heads)
+            is not None)
 
 
 def causal_mask(Sq: int, Skv: int, window: int = 0, offset: int = 0):
@@ -263,7 +314,7 @@ def causal_mask(Sq: int, Skv: int, window: int = 0, offset: int = 0):
 
 
 def attend_full(cfg, p, x, positions, *, window: int = 0,
-                use_flash: bool = False, bidirectional: bool = False):
+                bidirectional: bool = False):
     """Self-attention over a full sequence (train / prefill).
 
     Returns (out, (k, v)) so prefill can seed the decode cache.
@@ -274,11 +325,8 @@ def attend_full(cfg, p, x, positions, *, window: int = 0,
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
     S = x.shape[1]
-    if use_flash and not bidirectional:
-        from ..kernels.flash_attention import ops as flash_ops
-        o = flash_ops.flash_attention(q, k, v, window=window,
-                                      softcap=cfg.logit_softcap)
-        o = o.reshape(*o.shape[:2], -1)
+    if flash_kernel_engaged(cfg, S, bidirectional=bidirectional):
+        o = _flash_attention(q, k, v, window, float(cfg.logit_softcap))
     elif S >= CHUNKED_THRESHOLD:
         # long sequences: blocked online-softmax (O(S^2) logits never
         # materialize — required for the 32k prefill cells to fit HBM)

@@ -115,7 +115,7 @@ def _ffn_part(cfg, p, x, aux):
 
 
 def apply_block_seq(cfg, kind, p, x, positions, aux, *, cache=None,
-                    enc_out=None, bidirectional=False, use_flash=False):
+                    enc_out=None, bidirectional=False):
     """Full-sequence mode. Returns (x, aux, new_cache)."""
     h = apply_norm(cfg, p["ln1"], x)
     new_cache = None
@@ -144,7 +144,6 @@ def apply_block_seq(cfg, kind, p, x, positions, aux, *, cache=None,
     window = cfg.sliding_window if kind == "attn_local" or kind == "hymba" else 0
     with jax.named_scope("attn"):
         y, (k, v) = A.attend_full(cfg, p["attn"], h, positions, window=window,
-                                  use_flash=use_flash,
                                   bidirectional=bidirectional)
     if kind == "hymba":
         y = 0.5 * (y + ym)
@@ -310,8 +309,8 @@ def _logits(cfg, params, x):
     return (x @ head).astype(jnp.float32)
 
 
-def forward_train(cfg: ArchConfig, params, batch, use_flash: bool = False,
-                  remat: bool = False, seq_shard: bool = False):
+def forward_train(cfg: ArchConfig, params, batch, remat: bool = False,
+                  seq_shard: bool = False):
     """batch: dict(tokens (B,S) int32, + optional frames/patches).
     Returns (logits_f32 (B,S,V), aux dict).  ``remat=True`` checkpoints each
     scanned layer group (recompute in backward) to bound activation memory.
@@ -336,7 +335,7 @@ def forward_train(cfg: ArchConfig, params, batch, use_flash: bool = False,
         for i, kind in enumerate(kinds):
             p_i = jax.tree_util.tree_map(lambda a: a, blk_params[i])
             x, aux, _ = apply_block_seq(cfg, kind, p_i, x, positions, aux,
-                                        enc_out=enc_out, use_flash=use_flash)
+                                        enc_out=enc_out)
             if seq_shard:
                 x = shard_activations(x, seq_axis=seq_ax)
         return (x, aux), None
@@ -348,8 +347,7 @@ def forward_train(cfg: ArchConfig, params, batch, use_flash: bool = False,
     return _logits(cfg, params, x), {"moe_aux": aux[0], "moe_z": aux[1]}
 
 
-def forward_prefill(cfg: ArchConfig, params, batch, cache,
-                    use_flash: bool = False):
+def forward_prefill(cfg: ArchConfig, params, batch, cache):
     """Full-sequence prefill that fills the caches.
     Returns (last-token logits (B,V), new_cache)."""
     tokens = batch["tokens"]
@@ -371,7 +369,7 @@ def forward_prefill(cfg: ArchConfig, params, batch, cache,
             cache_g = jax.tree_util.tree_map(lambda c: c[g], cache_full[i])
             x, aux, nc = apply_block_seq(cfg, kind, blk_params[i], x,
                                          positions, aux, cache=cache_g,
-                                         enc_out=enc_out, use_flash=use_flash)
+                                         enc_out=enc_out)
             new_caches.append(jax.tree_util.tree_map(
                 lambda c, n: jax.lax.dynamic_update_index_in_dim(
                     c, n.astype(c.dtype), g, 0), cache_full[i], nc))

@@ -25,12 +25,13 @@ Each batch kernel opens a ``gr:prefill`` span (meta ``batch``, ``tokens``)
 around its prefill call and a ``gr:decode`` span (meta ``pos``) around each
 decode step; ``stats()["serving"]`` counts, per (prompt_len, new_tokens)
 shape, the prompt tokens prefilled, those of them whose Mamba layers ran
-the Pallas selective scan (``scan_kernel_tokens``), the decode steps issued
-and the bytes of the KV cache and of the SSM state that one batch's cache
-holds.
+the Pallas selective scan (``scan_kernel_tokens``) and whose attention
+layers ran the Pallas flash kernel (``flash_kernel_tokens``), the decode
+steps issued and the bytes of the KV cache and of the SSM state that one
+batch's cache holds.
 
 Per-slot ragged positions (token-level continuous batching) would need a
-vector-``pos`` decode mask; noted as future work in DESIGN.md.
+vector-``pos`` decode mask, which the decode step does not have.
 """
 from __future__ import annotations
 
@@ -48,7 +49,7 @@ from ..core import (DEFAULT_TENANT, GrScheduler, make_scheduler,
 from ..core.frontend import GrFunction, function
 from ..core.managed import ManagedValue
 from ..core.timeline import span
-from ..models import init_cache, layer_kinds, mamba
+from ..models import attention, init_cache, layer_kinds, mamba
 from ..models.config import ArchConfig
 from .steps import make_decode_step, make_prefill_step
 
@@ -117,9 +118,11 @@ class ServingEngine:
         # steady-state batches replay one plan instead of re-recording.
         self._fns: Dict[tuple, GrFunction] = {}
         self._shape_stats: Dict[tuple, dict] = {}
-        self._scan_kernel = (
-            any(k in ("mamba", "hymba") for k in layer_kinds(cfg))
-            and mamba.scan_kernel_engaged(cfg))
+        kinds = layer_kinds(cfg)
+        self._scan_kernel = (any(k in ("mamba", "hymba") for k in kinds)
+                             and mamba.scan_kernel_engaged(cfg))
+        self._attends = any(k.startswith("attn") or k == "hymba"
+                            for k in kinds)
 
     # ------------------------------------------------------------------
     def submit(self, tokens: np.ndarray, new_tokens: int = 0, *,
@@ -298,11 +301,14 @@ class ServingEngine:
         st = self._shape_stats.get((plen, ntok))
         if st is None:
             st = self._shape_stats[(plen, ntok)] = dict(
-                prefill_tokens=0, scan_kernel_tokens=0, decode_steps=0,
+                prefill_tokens=0, scan_kernel_tokens=0,
+                flash_kernel_tokens=0, decode_steps=0,
                 **cache_bytes(self.cfg, self.batch, plen + ntok))
         st["prefill_tokens"] += self.batch * plen
         if self._scan_kernel:
             st["scan_kernel_tokens"] += self.batch * plen
+        if self._attends and attention.flash_kernel_engaged(self.cfg, plen):
+            st["flash_kernel_tokens"] += self.batch * plen
         st["decode_steps"] += ntok - 1
 
     def stats(self) -> dict:

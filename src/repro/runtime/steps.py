@@ -25,11 +25,10 @@ class TrainState(NamedTuple):
     opt: AdamWState
 
 
-def make_loss_fn(cfg: ArchConfig, use_flash: bool = False,
-                 remat: bool = True, seq_shard: bool = False):
+def make_loss_fn(cfg: ArchConfig, remat: bool = True,
+                 seq_shard: bool = False):
     def loss_fn(params, micro_batch):
-        logits, aux = forward_train(cfg, params, micro_batch,
-                                    use_flash=use_flash, remat=remat,
+        logits, aux = forward_train(cfg, params, micro_batch, remat=remat,
                                     seq_shard=seq_shard)
         loss = cross_entropy_loss(logits, micro_batch["labels"])
         total = loss + aux["moe_aux"] + aux["moe_z"]
@@ -38,15 +37,14 @@ def make_loss_fn(cfg: ArchConfig, use_flash: bool = False,
 
 
 def make_train_step(cfg: ArchConfig, optimizer: AdamW, *,
-                    use_flash: bool = False, remat: bool = True,
-                    seq_shard: bool = False):
+                    remat: bool = True, seq_shard: bool = False):
     """Returns train_step(state, batch) -> (state, metrics).
 
     ``batch`` leaves are shaped (accum, micro_batch, ...): the step scans
     over the leading accumulation axis, accumulating f32 gradients, then
     applies one optimizer update.
     """
-    loss_fn = make_loss_fn(cfg, use_flash, remat, seq_shard)
+    loss_fn = make_loss_fn(cfg, remat, seq_shard)
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
     def train_step(state: TrainState, batch) -> Tuple[TrainState, Dict]:
@@ -75,9 +73,9 @@ def make_train_step(cfg: ArchConfig, optimizer: AdamW, *,
     return train_step
 
 
-def make_prefill_step(cfg: ArchConfig, use_flash: bool = False):
+def make_prefill_step(cfg: ArchConfig):
     def prefill_step(params, batch, cache):
-        return forward_prefill(cfg, params, batch, cache, use_flash=use_flash)
+        return forward_prefill(cfg, params, batch, cache)
     return prefill_step
 
 

@@ -1,5 +1,5 @@
 """TaskGraphTrainer — the paper's runtime scheduler as a first-class
-feature of the training loop (DESIGN.md §3).
+feature of the training loop.
 
 Every training step is issued as plain sequential host code; the GrJAX
 scheduler infers the dependency structure and overlaps:
@@ -55,7 +55,7 @@ class TaskGraphTrainer:
                  optimizer: Optional[AdamW] = None,
                  scheduler: Optional[GrScheduler] = None,
                  ckpt_dir: Optional[str] = None, ckpt_every: int = 10,
-                 use_flash: bool = False, remat: bool = True,
+                 remat: bool = True,
                  seed: int = 0,
                  straggler_factor: float = 3.0,
                  capture_steps: bool = True) -> None:
@@ -66,7 +66,6 @@ class TaskGraphTrainer:
         self.stream = SyntheticTokenStream(cfg, seq_len, global_batch,
                                            accum=accum, seed=seed)
         self.train_step = jax.jit(make_train_step(cfg, self.optimizer,
-                                                  use_flash=use_flash,
                                                   remat=remat),
                                   donate_argnums=(0,))
         self.ckpt = CheckpointManager(ckpt_dir) if ckpt_dir else None

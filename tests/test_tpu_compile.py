@@ -22,7 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.benchsuite import BENCHMARKS
 from repro.benchsuite import kernels as K
-from repro.kernels.flash_attention.kernel import flash_attention_bhsd
+from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.rmsnorm.kernel import rmsnorm_2d
 from repro.kernels.rwkv6.kernel import wkv6_bh
 from repro.kernels.selective_scan.ops import selective_scan
@@ -59,10 +59,21 @@ def _spec(shape, dtype, sharding):
 def test_flash_attention_compiles_at_hymba_widths(one_chip):
     # Hymba-1.5B prefill: 25 query heads over 5 KV heads, hd 64, SWA 1024.
     S, hd = 2048, 64
-    q = _spec((25, S, hd), jnp.bfloat16, one_chip)
-    kv = _spec((5, S, hd), jnp.bfloat16, one_chip)
-    compiled = flash_attention_bhsd.lower(
-        q, kv, kv, causal=True, window=1024, num_q_heads=25).compile()
+    q = _spec((1, S, 25, hd), jnp.bfloat16, one_chip)
+    kv = _spec((1, S, 5, hd), jnp.bfloat16, one_chip)
+    compiled = flash_attention.lower(q, kv, kv, window=1024).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("S", [8192, 2048])
+@pytest.mark.parametrize("with_lse", [False, True])
+def test_flash_attention_compiles_at_jamba_prefill_widths(one_chip, S,
+                                                          with_lse):
+    # Jamba2-3B prefill: a batch of 4 prompts, 20 query heads over one KV
+    # head of 128, causal; with the log-sum-exp, as a training forward.
+    q = _spec((4, S, 20, 128), jnp.bfloat16, one_chip)
+    kv = _spec((4, S, 1, 128), jnp.bfloat16, one_chip)
+    compiled = flash_attention.lower(q, kv, kv, with_lse=with_lse).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
